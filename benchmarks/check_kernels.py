@@ -24,8 +24,11 @@ import sys
 
 CSV_PATH = "experiments/kernels/fused_throughput.csv"
 
-# Ratchet-up only (see module docstring).  Current figures: fused/fori
-# clients/s ratio ~1.3-1.7 at cohorts 256/1024 on a 1-core CPU runner.
+# Ratchet-up only (see module docstring).  Current figures: the gate
+# fails.  With JAX 0.9 and the chunk fold as a loop of single adds (the
+# order the Pallas kernel can reproduce), the fused/fori clients/s ratio
+# at cohorts 256/1024 is ~0.14-0.16 on an 8-core CPU and ~0.08-0.12 on
+# one core; it was ~1.6 with JAX 0.4.37 and a batched reduce.
 RATIO_FLOOR = 1.0
 CROSSOVER_COHORT = 256           # fused must win from here up
 REQUIRED_COHORTS = (256, 1024)   # rows the CSV must contain

@@ -346,10 +346,11 @@ def bench_fused_kernel_throughput():
     post-compile in the same process, so the fused/fori ratio is a
     hardware-independent crossover figure; ``benchmarks.check_kernels``
     gates CI on ratio ≥ 1 at every cohort ≥ 256.  The autotune sweep
-    itself is excluded from the timings (cached winner after the first
-    run — see ``kernels/tune.py``).
+    itself is excluded from the timings, and its winners go to a
+    scratch cache that lives for this run only (``kernels/tune.py``).
     """
     import os
+    import tempfile
 
     from repro.core import fedscalar as fs
     from repro.kernels import ops, tune
@@ -358,6 +359,8 @@ def bench_fused_kernel_throughput():
                                jnp.float32)}
     cfg = fs.FedScalarConfig()
     rows = []
+    tune_dir = tempfile.TemporaryDirectory()
+    tune_cache = os.path.join(tune_dir.name, "fused_tune.json")
     for n in (8, 64, 256, 1024):
         seeds = fs.round_seeds(0, n)
         rs = jnp.asarray(np.random.RandomState(1).randn(n, 1), jnp.float32)
@@ -368,7 +371,8 @@ def bench_fused_kernel_throughput():
         cps_f = n / (us_f / 1e6)
         emit(f"fused_throughput_n{n}_fori", us_f, f"{cps_f:.0f}_clients/s")
 
-        best = tune.autotune_fused(512, 2048, n, 1, cfg.distribution.value)
+        best = tune.autotune_fused(512, 2048, n, 1, cfg.distribution.value,
+                                   cache_path=tune_cache)
         fused = jax.jit(lambda p, r, s, wt, b=best: ops.server_update_fused(
             p, r, s, weights=wt, distribution=cfg.distribution,
             use_pallas=b["impl"] == "pallas",
@@ -388,6 +392,7 @@ def bench_fused_kernel_throughput():
         for r in rows:
             f.write(f"{r[0]},{r[1]:.1f},{r[2]:.1f},{r[3]:.1f},{r[4]:.1f},"
                     f"{r[5]:.4f},{r[6]},{r[7]}\n")
+    tune_dir.cleanup()
 
 
 # ---------------------------------------------------------------------------
@@ -582,4 +587,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,4 +1,4 @@
-"""smollm-360m [dense]: llama-arch small. [hf:HuggingFaceTB/SmolLM-135M]
+"""smollm-360m [dense]: llama-arch small. [hf:HuggingFaceTB/SmolLM-360M]
 
 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152.
 """
@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
     d_ff=2560,
     vocab_size=49152,
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )

@@ -1,91 +1,27 @@
-"""Version shims over JAX APIs that moved between 0.4.x and 0.7.x.
+"""Mesh helpers over JAX's sharding-in-types surface.
 
-The repo targets the sharding-in-types surface (``jax.sharding
-.get_abstract_mesh``, ``AxisType``, ``jax.set_mesh``) but must also run
-on jax 0.4.37 where the ambient mesh is still the thread-resources
-*physical* mesh and ``Mesh`` has no axis types.  Everything
-version-dependent funnels through here so the rest of the codebase can
-use one spelling.
+The repo reads the ambient mesh (installed with ``jax.set_mesh``) as a
+plain ``{axis: size}`` dict, and builds every mesh with ``Auto`` axis
+types; these two helpers keep both in one place.
 """
 from __future__ import annotations
 
 
 import jax
 
-__all__ = ["ambient_mesh_axes", "use_mesh", "make_mesh",
-           "ensure_optimization_barrier_batching"]
-
-
-def ensure_optimization_barrier_batching() -> None:
-    """Register the (identity) vmap rule for ``optimization_barrier``.
-
-    jax 0.4.37 lowers ``jax.lax.optimization_barrier`` but never gave
-    its primitive a batching rule, so any ``vmap`` over a function that
-    uses the barrier (the fused megakernel's reduce pins one) dies with
-    ``NotImplementedError``.  The barrier is the identity on each
-    operand, so batching is dim-preserving bind — register exactly
-    that, only if the running jax hasn't already.
-    """
-    try:
-        from jax._src.lax.lax import optimization_barrier_p as prim
-    except ImportError:  # pragma: no cover - future jax moved/fixed it
-        return
-    from jax.interpreters import batching
-    if prim in batching.primitive_batchers:  # newer jax: rule exists
-        return
-
-    def _rule(args, dims, **params):
-        return prim.bind(*args, **params), dims
-
-    batching.primitive_batchers[prim] = _rule
-
-
-def _physical_context_mesh():
-    """The ``with mesh:`` context mesh on jax<0.5 (or None)."""
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:  # pragma: no cover - future jax may drop this
-        return None
-    pm = thread_resources.env.physical_mesh
-    if pm is None or pm.empty:
-        return None
-    return pm
+__all__ = ["ambient_mesh_axes", "make_mesh"]
 
 
 def ambient_mesh_axes() -> dict | None:
     """``{axis_name: size}`` of the ambient mesh, or None when meshless."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        m = gam()
-        if m is not None and not m.empty:
-            return dict(zip(m.axis_names, m.axis_sizes))
-    pm = _physical_context_mesh()
-    if pm is not None:
-        return dict(zip(pm.axis_names, pm.devices.shape))
-    return None
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use = getattr(jax.sharding, "use_mesh", None)
-    if use is not None:
-        return use(mesh)
-    # jax<0.5: Mesh is itself the context manager.
-    return mesh
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
+        return None
+    return dict(zip(m.axis_names, m.axis_sizes))
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names),
-            )
-        except TypeError:  # pragma: no cover - older make_mesh signature
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -8,9 +8,7 @@ the three is what the kernel tests assert.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 _TAG_U1 = 0x9E3779B9
 _TAG_U2 = 0x85EBCA6B
@@ -22,16 +20,6 @@ _TAG_HAD_TR = 0x165667B1
 _TAG_HAD_TC = 0x9E3779F9
 _HAD_MASK_FALLBACK = 0x9E3779B9
 SPARSE_S = 4
-
-
-def interpret_mode():
-    """Value for ``pallas_call(interpret=...)`` on non-TPU backends.
-
-    Newer jax wants a ``pltpu.InterpretParams`` instance (TPU-semantics
-    interpreter); jax<=0.4.x only accepts a bool.
-    """
-    params = getattr(pltpu, "InterpretParams", None)
-    return params() if params is not None else True
 
 
 def _u32(x):
@@ -60,8 +48,30 @@ def fold_seed(seed, leaf_tag):
     return splitmix32(_u32(seed) ^ splitmix32(_u32(leaf_tag)))
 
 
+def u32_to_f32(bits):
+    """uint32 → float32, correctly rounded, without a uint32→float32 cast.
+
+    Mosaic has no unsigned-to-float conversion.  Each 16-bit half
+    converts exactly through int32, ``hi·65536`` is exact, and the one
+    add rounds once — so this equals ``bits.astype(float32)`` to the bit
+    (an FMA contraction of the product cannot change that either).
+    """
+    bits = _u32(bits)
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & _u32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
+def flat_index(row, col, orig_cols: int):
+    """Leaf-local flat index of (row, col) as float32 (the block-mask
+    domain, exact below 2²⁴).  Coordinates are below 2³¹, so they
+    convert through int32, which Mosaic supports."""
+    return (row.astype(jnp.int32).astype(jnp.float32) * jnp.float32(orig_cols)
+            + col.astype(jnp.int32).astype(jnp.float32))
+
+
 def uniform01(bits):
-    return (bits.astype(jnp.float32) + 1.0) * jnp.float32(2.0**-32)
+    return (u32_to_f32(bits) + 1.0) * jnp.float32(2.0**-32)
 
 
 def parity32(x):

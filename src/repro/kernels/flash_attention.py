@@ -27,8 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.common import interpret_mode
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_call"]
 
@@ -112,7 +111,7 @@ def flash_attention_call(
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if interpret:
-        interpret = interpret_mode()
+        interpret = pltpu.InterpretParams()
 
     # fold: Q → (B·K, S, G·hd-rows): arrange as (B·K, S·G, hd)
     qf = (q.reshape(b, s, kh, g, hd).transpose(0, 2, 1, 3, 4)

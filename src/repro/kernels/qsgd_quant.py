@@ -18,7 +18,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.qsgd import QSGD_TAG
-from repro.kernels.common import fold_seed, hash_u32, interpret_mode, uniform01
+from repro.kernels.common import fold_seed, hash_u32, uniform01
 
 __all__ = ["qsgd_kernel_call"]
 
@@ -64,7 +64,7 @@ def qsgd_kernel_call(
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if interpret:
-        interpret = interpret_mode()
+        interpret = pltpu.InterpretParams()
     levels = (1 << (bits - 1)) - 1
     norm = jnp.linalg.norm(x2d.astype(jnp.float32).reshape(-1))
     norm = jnp.where(norm == 0, 1.0, norm).reshape(1)

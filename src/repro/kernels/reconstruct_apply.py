@@ -13,8 +13,10 @@ contract is the whole point:
     pad N to a multiple of FUSED_CHUNK (zero seeds, zero scalars);
     for block b = 0..k-1:                      # sequential
       for chunk c = 0..N/cb-1:                 # sequential
-        acc += sum_axis0( rs[c·cb+i, b] · v_i · mask_b  for i < cb )
+        acc += fold( rs[c·cb+i, b] · v_i · mask_b  for i < cb )
     y = x + acc                                # float32 acc throughout
+
+where ``fold`` is the left fold c₀ + c₁ + … + c_{cb-1} (``fold_chunk``).
 
 The scale is folded into the scalars *before* the sum, not applied to
 the accumulator after it, deliberately: a trailing ``x + scale·acc``
@@ -23,16 +25,16 @@ makes the output bits lowering-dependent — the Pallas interpreter and
 the XLA-jitted mirror disagreed on exactly that contraction.  A bare
 ``x + acc`` add is one correctly-rounded op everywhere.
 
-The per-chunk ``sum`` over the cb=FUSED_CHUNK client axis is a single
-reduction the compiler may vectorize freely — on CPU, XLA fuses
-direction generation *into* the reduce, which breaks the loop-carried
-add chain of the per-client fori kernel and is what finally puts the
-fused path ahead of the plain jnp fori loop (experiments/kernels/
-fused_throughput.csv).  The price: a chunk-batched reduction is a
-different float association than the original kernel's strictly
-sequential per-client adds, so the fused path is **its own numeric
-spec** — bit-identical across the Pallas kernel, the jnp mirror below
-and the independent ``ref.server_update_fused_ref`` oracle (asserted in
+The per-chunk fold over the cb=FUSED_CHUNK client axis is elementwise
+across the tile: each chunk's products are generated batched and
+materialized, then folded — which breaks the loop-carried add chain of
+the per-client fori kernel.  The fold's association is written out as
+explicit adds, so no lowering (XLA on CPU or TPU, Mosaic, the
+interpreter) can pick its own.  The price: chunk partials added into
+the accumulator are a different float association than the original
+kernel's strictly sequential per-client adds, so the fused path is
+**its own numeric spec** — bit-identical across the Pallas kernel, the
+jnp mirror below and the independent ``ref.server_update_fused_ref`` oracle (asserted in
 ``tests/test_kernel_differential.py``), and allclose (not bitwise) to
 the legacy fori/kernel paths.
 
@@ -42,7 +44,7 @@ The autotuner (``kernels/tune.py``) only sweeps parameters that cannot
 move bits — Pallas (br, bc) tile shapes and the mirror's row-slab
 height — because every element's value is a pure function of its global
 (row, col) and the chunk partials are elementwise (verified: the
-chunk-axis ``sum`` is bitwise invariant to spatial tiling).
+chunk fold is bitwise invariant to spatial tiling).
 
 Generation uses the factored direction chain (``common.row_state`` /
 ``tile_from_state``): stages 1–2 of the SplitMix32 chain are hoisted
@@ -64,11 +66,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import ensure_optimization_barrier_batching
 from repro.core.prng import PROJ_SALT
 from repro.kernels.common import (
+    flat_index,
     fold_seed,
-    interpret_mode,
     row_state,
     splitmix32,
     tile_from_state,
@@ -76,16 +77,12 @@ from repro.kernels.common import (
 
 __all__ = ["fused_reconstruct_apply", "FUSED_CHUNK", "DEFAULT_FUSED_BLOCK"]
 
-# jax 0.4.x ships optimization_barrier without a vmap rule; the reduce
-# below pins one, and callers are allowed to vmap the fused update.
-ensure_optimization_barrier_batching()
-
 # Clients regenerated per chunk partial.  Pinned: part of the numeric
 # spec (see module docstring), NOT autotunable.
 FUSED_CHUNK = 16
 
 # Default Pallas tile.  Smaller than the two-kernel default because the
-# kernel holds a (FUSED_CHUNK, br, bc) contribution stack in VMEM:
+# kernel holds a (FUSED_CHUNK, br, bc) product scratch in VMEM:
 # 16·128·256·4 B = 2 MiB, comfortably under budget with x, acc and y.
 DEFAULT_FUSED_BLOCK = (128, 256)
 
@@ -100,22 +97,37 @@ def _pad_cohort(seeds: jax.Array, rs: jax.Array):
     return seeds, rs, (n + pad) // FUSED_CHUNK
 
 
+def fold_chunk(term, n: int):
+    """The spec's chunk reduction: the left fold t₀ + t₁ + … + t_{n-1}
+    of ``term(i)``, one add per step.
+
+    A loop of single adds leaves no lowering (XLA on CPU or TPU, Mosaic,
+    the interpreter) room to pick its own association, as a reduce
+    would: XLA's jitted ``sum`` over the chunk axis does not add in this
+    order.  The Pallas kernel and the mirror both call this; the eager
+    oracle spells the same adds on its own.
+    """
+    return jax.lax.fori_loop(1, n, lambda i, t: t + term(i), term(0))
+
+
 def _chunk_partial(folded, rr, row, col, distribution, mask):
     """sum over the chunk axis of rₙ·vₙ(·mask) — the spec's inner term.
 
     ``folded``/``rr`` carry the chunk axis; ``row``/``col``/``mask``
     broadcast over it.  The contribution is computed exactly as the
-    oracle writes it — (r · v) · mask, v from the shared chain — so
-    equality with ``ref.server_update_fused_ref`` is bitwise.
+    oracle writes it — (r · v) · mask, v from the shared chain — and
+    reduced by :func:`fold_chunk`, so equality with
+    ``ref.server_update_fused_ref`` is bitwise.
 
     The optimization barrier pins the spec's "materialize products,
     then reduce" order in compiled lowerings: without it a fusion
-    context (jit, the Pallas kernel) may contract the multiply into
-    the reduction's adds as FMAs — which moves bits exactly for the
-    one family whose products round (gaussian; ±1/±2-valued families
-    have exact products and cannot tell).  The eager oracle
-    materializes the product array by construction.  Generation is the
-    other context-sensitive piece (see the mirror's chunk loop).
+    context (jit) may contract the multiply into the reduction's adds
+    as FMAs — which moves bits exactly for the one family whose
+    products round (gaussian; ±1/±2-valued families have exact products
+    and cannot tell).  The eager oracle materializes the product array
+    by construction; the Pallas kernel stores every product to a VMEM
+    scratch before it reduces.  Generation is the other
+    context-sensitive piece (see the mirror's chunk loop).
     """
     st = row_state(folded, row, distribution)
     v = tile_from_state(st, col, distribution)
@@ -123,7 +135,7 @@ def _chunk_partial(folded, rr, row, col, distribution, mask):
     if mask is not None:
         contrib = contrib * mask
     contrib = jax.lax.optimization_barrier(contrib)
-    return jnp.sum(contrib, axis=0)
+    return fold_chunk(lambda i: contrib[i], contrib.shape[0])
 
 
 # ---------------------------------------------------------------------------
@@ -131,10 +143,11 @@ def _chunk_partial(folded, rr, row, col, distribution, mask):
 # ---------------------------------------------------------------------------
 
 
-def _fused_kernel(seeds_ref, rs_ref, scale_ref, lo_ref, hi_ref, offs_ref,
-                  x_ref, o_ref, acc_ref, *, distribution: str,
+def _fused_kernel(seeds_ref, rs_ref, lo_ref, hi_ref, offs_ref,
+                  x_ref, o_ref, acc_ref, prod_ref, *, distribution: str,
                   num_chunks: int, num_blocks: int, masked: bool,
-                  block: tuple, leaf_tag: int, orig_cols: int):
+                  block: tuple, leaf_tag: int, orig_cols: int,
+                  padded_cohort: int):
     pi = pl.program_id(0)
     pj = pl.program_id(1)
     pb = pl.program_id(2)
@@ -157,28 +170,31 @@ def _fused_kernel(seeds_ref, rs_ref, scale_ref, lo_ref, hi_ref, offs_ref,
     salt = jnp.uint32(PROJ_SALT) + pb.astype(jnp.uint32)
 
     def chunk_sum(mask):
-        # The chunk is generated *batched* — a (cb, br, bc) contribution
-        # tensor reduced along the client axis in one op — not as cb
-        # stacked tiles: XLA lowers a stack-then-sum as a chain of adds,
-        # which is a different float association than the batched
-        # reduce the mirror/oracle use.  Batched generation keeps the
-        # lowering structurally identical, and the axis-0 reduce is
-        # elementwise invariant to the (br, bc) spatial tiling.
-        chunk_seeds = jnp.stack(
-            [seeds_ref[base + i] for i in range(FUSED_CHUNK)])
-        chunk_rs = jnp.stack(
-            [rs_ref[base + i, pb] for i in range(FUSED_CHUNK)])
-        folded = fold_seed(splitmix32(chunk_seeds ^ salt), leaf_tag)
-        acc_ref[...] += _chunk_partial(
-            folded[:, None, None], chunk_rs[:, None, None],
-            row[None, :, :], col[None, :, :], distribution,
-            None if mask is None else mask[None, :, :])
+        # Materialize the chunk's (cb, br, bc) products in VMEM, then
+        # fold them: the store/load boundary is what keeps the multiply
+        # out of the adds (the mirror's optimization barrier), and the
+        # fold is the spec's own association (``fold_chunk``).
+        rs_base = pb * padded_cohort + base     # rs is flat block-major
+
+        def product(i, carry):
+            folded = fold_seed(splitmix32(seeds_ref[base + i] ^ salt),
+                               leaf_tag)
+            v = tile_from_state(row_state(folded, row, distribution), col,  # fedlint: allow[FS004] kernel body IS the pinned numeric spec; interpret mode is pinned bitwise vs the mirror, the chip vs the oracle (DESIGN §11)
+                                distribution)
+            contrib = rs_ref[rs_base + i] * v
+            if mask is not None:
+                contrib = contrib * mask
+            prod_ref[i] = contrib
+            return carry
+
+        jax.lax.fori_loop(0, FUSED_CHUNK, product, 0)
+        acc_ref[...] += fold_chunk(lambda i: prod_ref[i], FUSED_CHUNK)
 
     if not masked:
         chunk_sum(None)
     else:
         # Same provably-empty-intersection skip as the two-kernel path.
-        r0 = (row_offset.astype(jnp.float32)
+        r0 = (row_offset.astype(jnp.int32).astype(jnp.float32)
               + pi.astype(jnp.float32) * jnp.float32(br))
         tile_lo = r0 * jnp.float32(orig_cols)
         tile_hi = (r0 + jnp.float32(br - 1) + 1.0) * jnp.float32(orig_cols)
@@ -186,18 +202,17 @@ def _fused_kernel(seeds_ref, rs_ref, scale_ref, lo_ref, hi_ref, offs_ref,
 
         @pl.when(overlap)
         def _():
-            flat = (row.astype(jnp.float32) * jnp.float32(orig_cols)
-                    + col.astype(jnp.float32))
+            flat = flat_index(row, col, orig_cols)
             mask = jnp.logical_and(flat >= lo_ref[pb], flat < hi_ref[pb])
             chunk_sum(mask.astype(jnp.float32))
 
     @pl.when(jnp.logical_and(pb == num_blocks - 1, pc == num_chunks - 1))
     def _():
-        y = x_ref[...].astype(jnp.float32) + scale_ref[0] * acc_ref[...]
+        y = x_ref[...].astype(jnp.float32) + acc_ref[...]
         o_ref[...] = y.astype(o_ref.dtype)
 
 
-def _fused_pallas(x2d, seeds, rs, leaf_tag, scale, distribution, block,
+def _fused_pallas(x2d, seeds, rs, leaf_tag, distribution, block,
                   row_offset, col_offset, lo, hi, orig_cols, masked,
                   interpret):
     rows, cols = x2d.shape
@@ -205,30 +220,25 @@ def _fused_pallas(x2d, seeds, rs, leaf_tag, scale, distribution, block,
     assert rows % br == 0 and cols % bc == 0, (x2d.shape, block)
     n, k = rs.shape
     seeds, rs, num_chunks = _pad_cohort(seeds, rs)
-    scale_arr = jnp.asarray(scale, jnp.float32).reshape(1)
+    padded_cohort = num_chunks * FUSED_CHUNK
     offs = jnp.stack([jnp.asarray(row_offset, jnp.uint32),
                       jnp.asarray(col_offset, jnp.uint32)])
     kern = functools.partial(
         _fused_kernel, distribution=distribution, num_chunks=num_chunks,
         num_blocks=k, masked=masked, block=block, leaf_tag=leaf_tag,
-        orig_cols=orig_cols)
+        orig_cols=orig_cols, padded_cohort=padded_cohort)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kern,
         grid=(rows // br, cols // bc, k, num_chunks),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((br, bc), lambda i, j, b, c: (i, j)),
-        ],
+        in_specs=[smem, smem, smem, smem, smem,
+                  pl.BlockSpec((br, bc), lambda i, j, b, c: (i, j))],
         out_specs=pl.BlockSpec((br, bc), lambda i, j, b, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x2d.dtype),
-        scratch_shapes=[pltpu.VMEM((br, bc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((br, bc), jnp.float32),
+                        pltpu.VMEM((FUSED_CHUNK, br, bc), jnp.float32)],
         interpret=interpret,
-    )(seeds, rs, scale_arr, lo, hi, offs, x2d)
+    )(seeds, rs.T.reshape(-1), lo, hi, offs, x2d)
 
 
 # ---------------------------------------------------------------------------
@@ -236,7 +246,7 @@ def _fused_pallas(x2d, seeds, rs, leaf_tag, scale, distribution, block,
 # ---------------------------------------------------------------------------
 
 
-def _mirror_span(x2d, folded, rs, scale, distribution, rowg, colg, lo, hi,
+def _mirror_span(x2d, folded, rs, distribution, rowg, colg, lo, hi,
                  orig_cols, masked, num_chunks):
     """Apply the fused spec to one row span of the matrix."""
     rows, cols = x2d.shape
@@ -245,8 +255,7 @@ def _mirror_span(x2d, folded, rs, scale, distribution, rowg, colg, lo, hi,
     col3 = colg[None, None, :]
     acc = jnp.zeros((rows, cols), jnp.float32)
     if masked:
-        flat = (rowg.astype(jnp.float32)[:, None] * jnp.float32(orig_cols)
-                + colg.astype(jnp.float32)[None, :])
+        flat = flat_index(rowg[:, None], colg[None, :], orig_cols)
     for b in range(k):
         mask = None
         if masked:
@@ -268,13 +277,11 @@ def _mirror_span(x2d, folded, rs, scale, distribution, rowg, colg, lo, hi,
             acc = acc + _chunk_partial(
                 sf[:, None, None], rr[:, None, None], row3, col3,
                 distribution, mask)
-    y = x2d.astype(jnp.float32) + jnp.asarray(scale, jnp.float32) * acc
-    return y.astype(x2d.dtype)
+    return (x2d.astype(jnp.float32) + acc).astype(x2d.dtype)
 
 
-def _fused_mirror(x2d, seeds, rs, leaf_tag, scale, distribution,
-                  row_offset, col_offset, lo, hi, orig_cols, masked,
-                  row_slab):
+def _fused_mirror(x2d, seeds, rs, leaf_tag, distribution, row_offset,
+                  col_offset, lo, hi, orig_cols, masked, row_slab):
     rows, cols = x2d.shape
     n, k = rs.shape
     seeds, rs, num_chunks = _pad_cohort(seeds, rs)
@@ -289,7 +296,7 @@ def _fused_mirror(x2d, seeds, rs, leaf_tag, scale, distribution,
         rowg = (jnp.arange(x_span.shape[0], dtype=jnp.uint32)
                 + ro + jnp.uint32(r0))
         return _mirror_span(
-            x_span, folded, rs, scale, distribution, rowg, colg, lo, hi,
+            x_span, folded, rs, distribution, rowg, colg, lo, hi,
             orig_cols, masked, num_chunks)
 
     # The row-slab height is a spatial partition only — per-element
@@ -347,7 +354,6 @@ def fused_reconstruct_apply(
     # then a bare add, immune to FMA-contraction differences between
     # lowerings (see module docstring).
     rs = rs * jnp.asarray(scale, jnp.float32)
-    scale = jnp.float32(1.0)
     n, k = rs.shape
     seeds = jnp.asarray(seeds, jnp.uint32)
     assert seeds.shape == (n,), (seeds.shape, rs.shape)
@@ -365,13 +371,13 @@ def fused_reconstruct_apply(
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
-        return _fused_mirror(x2d, seeds, rs, leaf_tag, scale, distribution,
+        return _fused_mirror(x2d, seeds, rs, leaf_tag, distribution,
                              row_offset, col_offset, lo, hi, orig_cols,
                              masked, row_slab)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if interpret:
-        interpret = interpret_mode()
-    return _fused_pallas(x2d, seeds, rs, leaf_tag, scale, distribution,
-                         block, row_offset, col_offset, lo, hi, orig_cols,
-                         masked, interpret)
+        interpret = pltpu.InterpretParams()
+    return _fused_pallas(x2d, seeds, rs, leaf_tag, distribution, block,
+                         row_offset, col_offset, lo, hi, orig_cols, masked,
+                         interpret)

@@ -58,7 +58,8 @@ def server_update_fused_ref(params: Any, rs, seeds, server_lr: float = 1.0,
     FUSED_CHUNK multiple, each chunk's ``(r·v)·mask`` contributions
     materialized via the **core library** generator (``block_seed`` +
     ``random_for_shape``, not the kernels' factored chain) and reduced
-    along the client axis, chunks and blocks accumulated sequentially
+    along the client axis by the spec's left fold c₀ + c₁ + … + c₁₅,
+    chunks and blocks accumulated sequentially
     in float32, final bare add into x.  O(chunk·d) memory — a test
     oracle, not a serving path.  ``tests/test_kernel_differential.py``
     asserts the Pallas megakernel, the jnp mirror and this function
@@ -99,7 +100,10 @@ def server_update_fused_ref(params: Any, rs, seeds, server_lr: float = 1.0,
                 mask = jnp.logical_and(flat >= lo[b],
                                        flat < hi[b]).astype(jnp.float32)
             for c in range(num_chunks):
-                contribs = []
+                # The spec's left fold, added as each product is made so
+                # only one chunk partial is alive (a list of 16 float32
+                # copies of the largest leaf would crowd a 16 GB chip).
+                partial = None
                 for i in range(c * FUSED_CHUNK, (c + 1) * FUSED_CHUNK):
                     sj = block_seed(seeds[i], b)
                     v = random_for_shape((rows, cols), sj, ll.tag,
@@ -107,8 +111,8 @@ def server_update_fused_ref(params: Any, rs, seeds, server_lr: float = 1.0,
                     contrib = rs[i, b] * v
                     if mask is not None:
                         contrib = contrib * mask
-                    contribs.append(contrib)
-                acc = acc + jnp.sum(jnp.stack(contribs), axis=0)
+                    partial = contrib if partial is None else partial + contrib
+                acc = acc + partial
         y = (x2d.astype(jnp.float32) + acc).astype(leaf.dtype)
         out.append(y.reshape(leaf.shape))
     return jax.tree_util.tree_unflatten(treedef, out)

@@ -15,8 +15,9 @@ scalar upload (DESIGN.md §6) makes the projection ordinal a real grid
 dimension: block j uses its own per-block seed and, in BLOCK mode, a
 flat-index mask restricting it to its contiguous slice of the leaf, so
 one compiled kernel emits all k scalars of ``r ∈ ℝᵏ`` in a single
-sweep over δ.  TPU grid iteration is sequential, so each (1, 1)
-float32 output tile accumulates partial sums across its (i, j) steps.
+sweep over δ.  TPU grid iteration is sequential, so block j's (8, bc)
+float32 output tile accumulates sublane partial sums across its (i, j)
+steps, and the wrapper reduces those 8·bc partials to the scalar.
 
 ``row_offset``/``col_offset`` shift the global coordinates so a shard
 of a model-parallel leaf projects exactly its slice — composition with
@@ -30,7 +31,7 @@ mask is applied), keeping the paper path bit-identical.
 Shapes/dtypes: x2d is a block-aligned float matrix; per-block seeds are
 uint32 ``(k,)``; block bounds are leaf-local flat indices as float32
 ``(k,)`` (exact below 2²⁴ elements per leaf — the jnp BLOCK path has
-the same float-mask domain); output is float32 ``(k, 1)``.
+the same float-mask domain); output is float32 ``(k,)``.
 """
 from __future__ import annotations
 
@@ -42,8 +43,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
+    flat_index,
     fold_seed,
-    interpret_mode,
     row_state,
     tile_from_state,
 )
@@ -52,6 +53,13 @@ __all__ = ["projection_kernel_call", "projection_blocks_kernel_call",
            "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = (256, 512)
+
+
+def _sublane_partial(t):
+    """(br, bc) → (8, bc) float32 partial sums: whole-vreg adds only, so
+    the accumulator keeps the (8, 128) tiling Mosaic can store."""
+    br, bc = t.shape
+    return jnp.sum(t.reshape(br // 8, 8, bc), axis=0)
 
 
 def _proj_kernel(seeds_ref, lo_ref, hi_ref, offs_ref, x_ref, o_ref, *,
@@ -77,21 +85,23 @@ def _proj_kernel(seeds_ref, lo_ref, hi_ref, offs_ref, x_ref, o_ref, *,
            + col_offset + pj.astype(jnp.uint32) * jnp.uint32(bc))
     st = row_state(seed_folded, row, distribution)
 
+    # Block b's (8, bc) output tile stays resident over its (i, j) sweep
+    # and accumulates sublane partials; the caller reduces it.
     @pl.when(jnp.logical_and(pi == 0, pj == 0))
     def _init():
-        o_ref[0, 0] = jnp.float32(0.0)
+        o_ref[...] = jnp.zeros((8, bc), jnp.float32)
 
     if not masked:
         # Paper k=1 path and FULL-mode multi-projections: every scalar
         # spans the whole leaf — no mask multiply (bit-identical k=1,
         # and no float32 flat-index domain limit).
         v = tile_from_state(st, col, distribution)
-        o_ref[0, 0] += jnp.sum(x_ref[...].astype(jnp.float32) * v)
+        o_ref[...] += _sublane_partial(x_ref[...].astype(jnp.float32) * v)
     else:
         # Skip (tile, block) pairs with provably empty intersection —
         # blocks partition the flat index space, so each tile overlaps
         # only ~1-2 of the k blocks and the rest cost one comparison.
-        r0 = (row_offset.astype(jnp.float32)
+        r0 = (row_offset.astype(jnp.int32).astype(jnp.float32)
               + pi.astype(jnp.float32) * jnp.float32(br))
         tile_lo = r0 * jnp.float32(orig_cols)
         tile_hi = (r0 + jnp.float32(br - 1) + 1.0) * jnp.float32(orig_cols)
@@ -100,10 +110,9 @@ def _proj_kernel(seeds_ref, lo_ref, hi_ref, offs_ref, x_ref, o_ref, *,
         @pl.when(overlap)
         def _():
             v = tile_from_state(st, col, distribution)
-            flat = (row.astype(jnp.float32) * jnp.float32(orig_cols)
-                    + col.astype(jnp.float32))
+            flat = flat_index(row, col, orig_cols)
             mask = jnp.logical_and(flat >= lo_ref[pb], flat < hi_ref[pb])
-            o_ref[0, 0] += jnp.sum(
+            o_ref[...] += _sublane_partial(
                 x_ref[...].astype(jnp.float32) * v * mask.astype(jnp.float32))
 
 
@@ -134,7 +143,8 @@ def projection_blocks_kernel_call(
     """
     rows, cols = x2d.shape
     br, bc = block
-    assert rows % br == 0 and cols % bc == 0, (x2d.shape, block)
+    assert rows % br == 0 and cols % bc == 0 and br % 8 == 0, (x2d.shape,
+                                                               block)
     k = seeds.shape[0]
     if masked is None:
         masked = k > 1
@@ -143,7 +153,7 @@ def projection_blocks_kernel_call(
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if interpret:
-        interpret = interpret_mode()
+        interpret = pltpu.InterpretParams()
     seeds_folded = jax.vmap(lambda s: fold_seed(s, leaf_tag))(seeds)
     offs = jnp.stack([jnp.asarray(row_offset, jnp.uint32),
                       jnp.asarray(col_offset, jnp.uint32)])
@@ -161,12 +171,12 @@ def projection_blocks_kernel_call(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((br, bc), lambda b, i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, 1), jnp.float32),
+        out_specs=pl.BlockSpec((8, bc), lambda b, i, j: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((k * 8, bc), jnp.float32),
         interpret=interpret,
     )(seeds_folded, jnp.asarray(lo, jnp.float32), jnp.asarray(hi, jnp.float32),
       offs, x2d)
-    return out[:, 0]
+    return jnp.sum(out.reshape(k, 8 * bc), axis=1)
 
 
 def projection_kernel_call(

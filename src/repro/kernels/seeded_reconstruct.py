@@ -46,7 +46,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.prng import PROJ_SALT
-from repro.kernels.common import fold_seed, gen_tile, interpret_mode, splitmix32
+from repro.kernels.common import (
+    flat_index,
+    fold_seed,
+    gen_tile,
+    splitmix32,
+)
 
 __all__ = ["reconstruct_kernel_call", "CLIENT_CHUNK"]
 
@@ -99,7 +104,7 @@ def _rec_kernel(seeds_ref, rs_ref, scale_ref, lo_ref, hi_ref, offs_ref, x_ref,
         # blocks partition the flat index space, so each tile overlaps
         # only ~1-2 of the k blocks; the other grid steps cost one
         # comparison instead of a chunk of hash-chains.
-        r0 = (row_offset.astype(jnp.float32)
+        r0 = (row_offset.astype(jnp.int32).astype(jnp.float32)
               + pi.astype(jnp.float32) * jnp.float32(br))
         tile_lo = r0 * jnp.float32(orig_cols)
         tile_hi = (r0 + jnp.float32(br - 1) + 1.0) * jnp.float32(orig_cols)
@@ -107,8 +112,7 @@ def _rec_kernel(seeds_ref, rs_ref, scale_ref, lo_ref, hi_ref, offs_ref, x_ref,
 
         @pl.when(overlap)
         def _():
-            flat = (row.astype(jnp.float32) * jnp.float32(orig_cols)
-                    + col.astype(jnp.float32))
+            flat = flat_index(row, col, orig_cols)
             mask = jnp.logical_and(flat >= lo_ref[pb], flat < hi_ref[pb])
             chunk_sum(mask.astype(jnp.float32))
 
@@ -166,7 +170,7 @@ def reconstruct_kernel_call(
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if interpret:
-        interpret = interpret_mode()
+        interpret = pltpu.InterpretParams()
     chunk = min(client_chunk, n)
     pad = (-n) % chunk
     if pad:

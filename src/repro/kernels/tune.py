@@ -52,11 +52,13 @@ __all__ = [
     "PALLAS_BLOCKS",
 ]
 
-DEFAULT_CACHE_PATH = os.environ.get(
-    "REPRO_TUNE_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "fedscalar-kernels",
-                 "fused_tune.json"),
-)
+# The serving cache, kept with the code and only ever read here, so the
+# tile a run picks depends only on files in the checkout.  Sweeps write
+# to the path their caller names; promoting a winner measured on a
+# device into this file is a deliberate, committed step.  Absent means
+# the default tiles.
+DEFAULT_CACHE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                  "fused_tune.json")
 
 # Candidate spaces.  Mirror slabs: None = whole matrix in one span.
 MIRROR_ROW_SLABS = (None, 16, 64, 256)
@@ -169,8 +171,7 @@ def cached_fused_params(rows: int, cols: int, cohort: int, k: int,
 
 def autotune_fused(rows: int, cols: int, cohort: int, k: int,
                    distribution: str = "rademacher", dtype_bits: int = 32,
-                   backend: str | None = None,
-                   cache_path: str = DEFAULT_CACHE_PATH,
+                   backend: str | None = None, *, cache_path: str,
                    measure=None) -> dict:
     """Winner params for a fused workload, sweeping once and caching.
 
@@ -178,8 +179,10 @@ def autotune_fused(rows: int, cols: int, cohort: int, k: int,
     "row_slab": int|None}``.  A cache hit short-circuits the sweep
     entirely — the stored winner is returned as-is, making repeat calls
     (and calls from other processes) deterministic and cheap.
-    ``measure`` is injectable for tests; the default times the real
-    fused call (median of 3 after warmup).
+    ``cache_path`` is where the sweep reads and writes its winners; it
+    has no default, so no sweep writes the serving cache
+    (``DEFAULT_CACHE_PATH``).  ``measure`` is injectable for tests; the
+    default times the real fused call (median of 3 after warmup).
     """
     if backend is None:
         backend = jax.default_backend()

@@ -36,7 +36,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.prng import PROJ_SALT, Distribution
@@ -446,10 +445,10 @@ def sharded_apply_blocks(
         return tuple(out)
 
     specs = fed_param_specs(plan, mesh)
-    return list(shard_map(
+    return list(jax.shard_map(
         apply_local, mesh=mesh,
         in_specs=(upload_spec(), upload_spec()) + specs,
-        out_specs=specs, check_rep=False,
+        out_specs=specs, check_vma=False,
     )(seeds, rs, *blocks))
 
 
@@ -534,8 +533,8 @@ def sharded_project_tree(
         return jax.lax.psum(acc, _mesh_axes(mesh))
 
     specs = fed_param_specs(plan, mesh)
-    return shard_map(
+    return jax.shard_map(
         project_local, mesh=mesh,
         in_specs=(upload_spec(),) + specs,
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(proj_seeds, *blocks)

@@ -22,7 +22,7 @@ from repro.configs.registry import get_arch
 from repro.launch.train import FLRunConfig, make_train_step
 from repro.sharding.rules import param_specs, named, input_specs_sharding
 
-from repro.core.compat import make_mesh, use_mesh
+from repro.core.compat import make_mesh
 
 mesh = make_mesh((2, 4), ("data", "model"))
 arch = get_arch("smollm-360m", reduced=True)
@@ -42,7 +42,7 @@ pspec = param_specs(jax.tree_util.tree_map(
     lambda w: jax.ShapeDtypeStruct(w.shape, w.dtype), params), mesh)
 pshard = named(mesh, pspec)
 bshard = named(mesh, input_specs_sharding(batch, mesh, 8))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     p8, m8 = jax.jit(step, in_shardings=(pshard, bshard, None),
                      out_shardings=(pshard, None))(params, batch, jnp.int32(0))
 
