@@ -51,6 +51,8 @@ from repro.fed.runtime import (
     ServerConfig,
     run_federation,
 )
+from repro.fed.runtime.engine import cohort_sampler
+from repro.fed.runtime.sampling import sampling_diagnostic
 from repro.models.mlp_classifier import init_mlp
 
 
@@ -182,7 +184,8 @@ def main():
               f"({s['queue_entry_bytes']} B/entry)")
 
     print("\n== unbiased-estimate diagnostics ==")
-    diag = h["sampling_diagnostic"]
+    diag = sampling_diagnostic(cohort_sampler(cfg, clients),
+                               rounds=min(200, 4 * cfg.rounds))
     print(f"  Horvitz–Thompson probe estimate rel. err : "
           f"{diag['estimate_rel_err']:.4f}")
     print(f"  empirical inclusion-marginal abs. err    : "

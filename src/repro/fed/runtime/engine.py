@@ -74,16 +74,13 @@ from typing import TYPE_CHECKING, Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import fedscalar as fs
 from repro.core.prng import Distribution
 from repro.core.projection import tree_size
 from repro.fed.costmodel import ChannelConfig, CostModel
-from repro.fed.runtime.sampling import (
-    ClientPopulation,
-    CohortSampler,
-    sampling_diagnostic,
-)
+from repro.fed.runtime.sampling import ClientPopulation, CohortSampler
 from repro.fed.runtime.server import ServerConfig, StreamingAggregator, Upload
 from repro.fed.runtime.transport import (
     DownlinkChannel,
@@ -97,7 +94,7 @@ if TYPE_CHECKING:
     from repro.fed.runtime.scheduler import SchedulerConfig
 
 __all__ = ["RuntimeConfig", "EngineCore", "run_federation",
-           "draw_cohort_batches", "StatefulClient"]
+           "draw_cohort_batches", "StatefulClient", "cohort_sampler"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,6 +233,21 @@ def draw_cohort_batches(cx, cy, num_shards: int, seed: int, round_idx,
     by = jnp.take_along_axis(
         sy, idx.reshape(chunk, S * B), axis=1).reshape(chunk, S, B)
     return bx, by
+
+
+def cohort_sampler(cfg: RuntimeConfig, client_sets,
+                   client_weights: np.ndarray | None = None) -> CohortSampler:
+    """The cohort sampler a run of ``cfg`` over ``client_sets`` draws
+    from; the ``weighted`` sampler defaults to the shard size behind
+    each virtual client."""
+    if client_weights is None and cfg.sampler == "weighted":
+        shard_sizes = np.asarray([len(y) for _, y in client_sets],
+                                 np.float64)  # fedlint: allow[FS002] host-side PPS sampling weights, never enters a device computation
+        client_weights = shard_sizes[np.arange(cfg.population)
+                                     % len(client_sets)]
+    population = ClientPopulation(cfg.population, weights=client_weights)
+    return CohortSampler(population, cfg.participation, cfg.sampler,
+                         seed=cfg.seed)
 
 
 def _fused_method(cfg: RuntimeConfig, num_shards: int) -> str | None:
@@ -433,14 +445,7 @@ class EngineCore:
         cx, cy = _stack_clients(client_sets)      # (#shards, n_per, feat...)
         xt, yt = jnp.asarray(x_test), jnp.asarray(y_test)
 
-        if client_weights is None and cfg.sampler == "weighted":
-            # default PPS weights: the shard size behind each virtual client
-            shard_sizes = np.asarray([len(y) for _, y in client_sets],
-                                     np.float64)  # fedlint: allow[FS002] host-side PPS sampling weights, never enters a device computation
-            client_weights = shard_sizes[np.arange(cfg.population) % num_shards]
-        population = ClientPopulation(cfg.population, weights=client_weights)
-        self.sampler = CohortSampler(population, cfg.participation,
-                                     cfg.sampler, seed=cfg.seed)
+        self.sampler = cohort_sampler(cfg, client_sets, client_weights)
         self.cm = CostModel(
             cfg.channel, fedavg_bits_per_client=d * cfg.channel.float_bits,
             rng_seed=cfg.seed)
@@ -575,20 +580,24 @@ class EngineCore:
     def compute_cohort(self, params, k: int, ids: np.ndarray):
         """Cohort local rounds in fixed-shape chunks (pad by repeating id 0)
         → (float32 (C, payload_dim) payloads, uint32 (C,) seeds)."""
-        c = len(ids)
-        rs_np = np.zeros((max(c, 1), self.proto.payload_dim), np.float32)
-        seeds_np = np.zeros(max(c, 1), np.uint32)
-        chunk = self.cfg.client_chunk
-        for lo in range(0, c, chunk):
-            part = ids[lo:lo + chunk]
-            padded = np.zeros(chunk, np.int64) if len(part) < chunk else part
-            if len(part) < chunk:
-                padded[:len(part)] = part
-            rs_c, seeds_c = self.chunk_payloads(params, jnp.uint32(k),
-                                                jnp.asarray(padded, jnp.uint32))
-            rs_np[lo:lo + len(part)] = np.asarray(rs_c)[:len(part)]
-            seeds_np[lo:lo + len(part)] = np.asarray(seeds_c)[:len(part)]
-        return rs_np, seeds_np
+        with TraceAnnotation("fed.compute_cohort"):
+            c = len(ids)
+            rs_np = np.zeros((max(c, 1), self.proto.payload_dim), np.float32)
+            seeds_np = np.zeros(max(c, 1), np.uint32)
+            chunk = self.cfg.client_chunk
+            for lo in range(0, c, chunk):
+                part = ids[lo:lo + chunk]
+                padded = (np.zeros(chunk, np.int64) if len(part) < chunk
+                          else part)
+                if len(part) < chunk:
+                    padded[:len(part)] = part
+                rs_c, seeds_c = self.chunk_payloads(
+                    params, jnp.uint32(k), jnp.asarray(padded, jnp.uint32))
+                with TraceAnnotation("fed.device_wait"):
+                    rs_np[lo:lo + len(part)] = np.asarray(rs_c)[:len(part)]
+                    seeds_np[lo:lo + len(part)] = \
+                        np.asarray(seeds_c)[:len(part)]
+            return rs_np, seeds_np
 
     def offer_uploads(self, ids, weights, k: int, tx,
                       deadline_s: float | None = None) -> None:
@@ -596,13 +605,14 @@ class EngineCore:
         client-id order (the deterministic aggregation order).
         ``deadline_s=None`` keeps the config deadline (legacy loop);
         the scheduler passes its per-round effective close instead."""
-        for i in range(len(ids)):
-            self.agg.offer(Upload(
-                client_id=int(ids[i]), encoded_round=k,
-                seed=int(tx.seeds[i]), r=tx.r_hat[i],
-                agg_weight=float(weights[i]),
-                latency_s=float(tx.latency_s[i]), lost=bool(tx.lost[i])),
-                deadline_s=deadline_s)
+        with TraceAnnotation("fed.offer_uploads"):
+            for i in range(len(ids)):
+                self.agg.offer(Upload(
+                    client_id=int(ids[i]), encoded_round=k,
+                    seed=int(tx.seeds[i]), r=tx.r_hat[i],
+                    agg_weight=float(weights[i]),
+                    latency_s=float(tx.latency_s[i]), lost=bool(tx.lost[i])),
+                    deadline_s=deadline_s)
 
     def apply_round(self, params, aseeds, acoeffs, ars, cohort_size: int, st):
         """Fold a closed round's buffers into the model.
@@ -612,71 +622,76 @@ class EngineCore:
         for both drivers, and ``method`` is what the digest replay must
         pin (it threads opaquely to :meth:`close_digest`).
         """
-        a = len(aseeds)
-        use_kernel: bool | str = False
-        apply_s = 0.0
-        if a and not st.skipped:
-            t_apply = time.perf_counter()
-            if self.proto.name == "fedscalar":
-                rs_b, w_b, seeds_b = _pad_bucket(ars, acoeffs, aseeds)
-                # mesh apply ≡ fori bitwise (DESIGN §7), so the shadow
-                # replay must NOT take the kernel path on mesh rounds —
-                # the kernel differs by ulps (DESIGN §9).
-                if (self.mesh is None
-                        and self.cfg.projection_mode == "fused_kernel"):
-                    use_kernel = "fused"
-                elif (self.mesh is None
-                        and self.kern_thresh is not None
-                        and a >= self.kern_thresh
-                        and (self.cfg.num_projections == 1
-                             or self.cfg.projection_mode == "block")):
-                    use_kernel = True
-                if self.mesh is not None:
-                    applier = self.apply_mesh
-                elif use_kernel == "fused":
-                    applier = self.apply_fused
+        with TraceAnnotation("fed.apply_round", rows=len(aseeds)):
+            a = len(aseeds)
+            use_kernel: bool | str = False
+            apply_s = 0.0
+            if a and not st.skipped:
+                t_apply = time.perf_counter()
+                if self.proto.name == "fedscalar":
+                    rs_b, w_b, seeds_b = _pad_bucket(ars, acoeffs, aseeds)
+                    # mesh apply ≡ fori bitwise (DESIGN §7), so the shadow
+                    # replay must NOT take the kernel path on mesh rounds —
+                    # the kernel differs by ulps (DESIGN §9).
+                    if (self.mesh is None
+                            and self.cfg.projection_mode == "fused_kernel"):
+                        use_kernel = "fused"
+                    elif (self.mesh is None
+                            and self.kern_thresh is not None
+                            and a >= self.kern_thresh
+                            and (self.cfg.num_projections == 1
+                                 or self.cfg.projection_mode == "block")):
+                        use_kernel = True
+                    if self.mesh is not None:
+                        applier = self.apply_mesh
+                    elif use_kernel == "fused":
+                        applier = self.apply_fused
+                    else:
+                        applier = (self.apply_kernel if use_kernel
+                                   else self.apply_fori)
+                    params = applier(params, jnp.asarray(rs_b),
+                                     jnp.asarray(seeds_b), jnp.asarray(w_b))
                 else:
-                    applier = self.apply_kernel if use_kernel else self.apply_fori
-                params = applier(params, jnp.asarray(rs_b),
-                                 jnp.asarray(seeds_b), jnp.asarray(w_b))
-            else:
-                uniform_exact = (self.cfg.sampler == "uniform"
-                                 and a == cohort_size
-                                 and st.applied_stale == 0
-                                 and bool(np.all(acoeffs == acoeffs[0])))
-                if uniform_exact:
-                    params = self.apply_mean(params, jnp.asarray(ars))
-                else:
-                    rs_b, w_b = _pad_bucket(ars, acoeffs)
-                    params = self.apply_weighted(params, jnp.asarray(rs_b),
-                                                 jnp.asarray(w_b))
-            jax.block_until_ready(jax.tree_util.tree_leaves(params))
-            apply_s = time.perf_counter() - t_apply
-        return params, use_kernel, apply_s
+                    uniform_exact = (self.cfg.sampler == "uniform"
+                                     and a == cohort_size
+                                     and st.applied_stale == 0
+                                     and bool(np.all(acoeffs == acoeffs[0])))
+                    if uniform_exact:
+                        params = self.apply_mean(params, jnp.asarray(ars))
+                    else:
+                        rs_b, w_b = _pad_bucket(ars, acoeffs)
+                        params = self.apply_weighted(
+                            params, jnp.asarray(rs_b), jnp.asarray(w_b))
+                with TraceAnnotation("fed.device_wait"):
+                    jax.block_until_ready(jax.tree_util.tree_leaves(params))
+                apply_s = time.perf_counter() - t_apply
+            return params, use_kernel, apply_s
 
     def close_digest(self, k: int, aseeds, acoeffs, ars, st, ids, params,
                      use_kernel: bool | str) -> int:
         """Digest-mode round close: broadcast the round's digest, mark
         the cohort synced, shadow-verify the replay → broadcast bits."""
-        applied_round = bool(len(aseeds)) and not st.skipped
-        dg = RoundDigest(
-            round_idx=k,
-            seeds=aseeds if applied_round else np.zeros(0, np.uint32),
-            rs=(ars if applied_round
-                else np.zeros((0, self.proto.payload_dim), np.float32)),
-            coeffs=(acoeffs.astype(np.float32) if applied_round
-                    else np.zeros(0, np.float32)))
-        bits = self.downlink.broadcast(dg)
-        self.client_last[ids] = k + 1   # the cohort heard the close broadcast
-        if self.shadow is not None:
-            self.shadow.apply_digest(dg, use_kernel=use_kernel)
-            for x, y in zip(jax.tree_util.tree_leaves(params),
-                            jax.tree_util.tree_leaves(self.shadow.params)):
-                if not np.array_equal(np.asarray(x), np.asarray(y)):
-                    raise AssertionError(
-                        f"digest replay diverged from the server at "
-                        f"round {k} (DESIGN §9 invariant)")
-        return bits
+        with TraceAnnotation("fed.close_digest"):
+            applied_round = bool(len(aseeds)) and not st.skipped
+            dg = RoundDigest(
+                round_idx=k,
+                seeds=aseeds if applied_round else np.zeros(0, np.uint32),
+                rs=(ars if applied_round
+                    else np.zeros((0, self.proto.payload_dim), np.float32)),
+                coeffs=(acoeffs.astype(np.float32) if applied_round
+                        else np.zeros(0, np.float32)))
+            bits = self.downlink.broadcast(dg)
+            self.client_last[ids] = k + 1   # the cohort heard the broadcast
+            if self.shadow is not None:
+                self.shadow.apply_digest(dg, use_kernel=use_kernel)
+                for x, y in zip(
+                        jax.tree_util.tree_leaves(params),
+                        jax.tree_util.tree_leaves(self.shadow.params)):
+                    if not np.array_equal(np.asarray(x), np.asarray(y)):
+                        raise AssertionError(
+                            f"digest replay diverged from the server at "
+                            f"round {k} (DESIGN §9 invariant)")
+            return bits
 
     @staticmethod
     def new_history(K: int) -> dict:
@@ -726,8 +741,6 @@ class EngineCore:
             sim_compute_seconds=time.perf_counter() - t0,
             fused_path=False,
             pending_rounds=self.agg.pending_rounds(),
-            sampling_diagnostic=sampling_diagnostic(self.sampler,
-                                                    rounds=min(200, 4 * K)),  # fedlint: allow[FS003] diagnostic round count, not a wire width
             sharding=self.shard_info,
             recon_clients_per_s=recon_clients_per_s,
             downlink_mode=cfg.downlink_mode,
@@ -994,7 +1007,5 @@ def _run_fused(cfg: RuntimeConfig, init_params, client_sets, x_test, y_test,
         downlink_stats=dict(broadcast_bits=total_dl, catchup_bits=0,
                             dense_resyncs=0),
         round_log=round_log,
-        sampling_diagnostic=dict(empirical_marginal_abs_err=0.0,
-                                 estimate_rel_err=0.0),
     )
     return h

@@ -31,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "ClientPopulation",
@@ -142,28 +143,30 @@ class CohortSampler:
         return np.random.RandomState(x)
 
     def sample(self, round_idx: int) -> Cohort:
-        n = self.population.num_clients
-        rng = self._rng(round_idx)
-        if self.kind == "uniform":
-            c = self._cohort_size
-            ids = np.sort(rng.choice(n, size=c, replace=False))
-            pi = np.full(c, c / n)
-        elif self.kind == "weighted":
-            pi_all = self._pps_pi
-            # systematic PPS: inclusion probability is exactly π_n
-            cum = np.cumsum(pi_all)
-            start = rng.uniform(0.0, 1.0)
-            ticks = start + np.arange(int(np.ceil(cum[-1] - start)))
-            ids = np.searchsorted(cum, ticks, side="right")
-            ids = np.unique(ids[ids < n])
-            pi = pi_all[ids]
-        else:  # poisson
-            mask = rng.random_sample(n) < self.participation
-            ids = np.where(mask)[0]
-            pi = np.full(len(ids), self.participation)
-        weights = 1.0 / (n * pi)
-        return Cohort(round_idx=round_idx, client_ids=ids.astype(np.int64),
-                      inclusion_probs=pi, agg_weights=weights)
+        with TraceAnnotation("fed.sample_cohort"):
+            n = self.population.num_clients
+            rng = self._rng(round_idx)
+            if self.kind == "uniform":
+                c = self._cohort_size
+                ids = np.sort(rng.choice(n, size=c, replace=False))
+                pi = np.full(c, c / n)
+            elif self.kind == "weighted":
+                pi_all = self._pps_pi
+                # systematic PPS: inclusion probability is exactly π_n
+                cum = np.cumsum(pi_all)
+                start = rng.uniform(0.0, 1.0)
+                ticks = start + np.arange(int(np.ceil(cum[-1] - start)))
+                ids = np.searchsorted(cum, ticks, side="right")
+                ids = np.unique(ids[ids < n])
+                pi = pi_all[ids]
+            else:  # poisson
+                mask = rng.random_sample(n) < self.participation
+                ids = np.where(mask)[0]
+                pi = np.full(len(ids), self.participation)
+            weights = 1.0 / (n * pi)
+            return Cohort(round_idx=round_idx,
+                          client_ids=ids.astype(np.int64),
+                          inclusion_probs=pi, agg_weights=weights)
 
 
 def realized_cohort_weights(cohort: Cohort, arrived: np.ndarray) -> np.ndarray:

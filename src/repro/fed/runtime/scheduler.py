@@ -63,6 +63,7 @@ import math
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.fed.costmodel import pipelined_round_start
 from repro.fed.runtime.sampling import realized_cohort_weights
@@ -286,6 +287,15 @@ def _corrected_weights(cohort, arrived: np.ndarray) -> np.ndarray:
     return w
 
 
+def _evaluate(core, params, hist: dict, k: int) -> None:
+    """Round ``k``'s test loss and accuracy into ``hist``."""
+    with TraceAnnotation("fed.evaluate"):
+        loss, acc = core.evaluate(params)
+        with TraceAnnotation("fed.device_wait"):
+            hist["loss"][k] = float(loss)
+            hist["accuracy"][k] = float(acc)
+
+
 def _run_sync(core, init_params, sched: SchedulerConfig) -> dict:
     """Admission-controlled synchronous serving: one round in flight.
 
@@ -315,80 +325,80 @@ def _run_sync(core, init_params, sched: SchedulerConfig) -> dict:
     agg_bytes_peak = 0
 
     for k in range(K):
-        cohort = core.sampler.sample(k)
-        ids = cohort.client_ids
-        if core.digest_mode:
-            catchup_bits, _, resyncs = downlink.catch_up_batch(
-                core.client_last[ids], k)
-            downlink_bits = catchup_bits
-            hist["catchup_bits"][k] = catchup_bits
-            hist["dense_resyncs"][k] = resyncs
-        else:
-            downlink_bits = downlink.broadcast()
+        with TraceAnnotation("fed.round", round=k):
+            cohort = core.sampler.sample(k)
+            ids = cohort.client_ids
+            if core.digest_mode:
+                catchup_bits, _, resyncs = downlink.catch_up_batch(
+                    core.client_last[ids], k)
+                downlink_bits = catchup_bits
+                hist["catchup_bits"][k] = catchup_bits
+                hist["dense_resyncs"][k] = resyncs
+            else:
+                downlink_bits = downlink.broadcast()
 
-        c = len(ids)
-        offered_total += c
-        rs_np, seeds_np = core.compute_cohort(params, k, ids)
-        tx = uplink.transmit(rs_np[:c], seeds_np[:c]) if c else None
+            c = len(ids)
+            offered_total += c
+            rs_np, seeds_np = core.compute_cohort(params, k, ids)
+            tx = uplink.transmit(rs_np[:c], seeds_np[:c]) if c else None
 
-        # --- quorum-xor-deadline closure (the effective cutoff) ---
-        if c and sched.quorum_frac < 1.0:
-            eff_deadline, reason = quorum_close_time(
-                tx.latency_s[~tx.lost], c, sched.quorum_frac, deadline)
-            closed_by_quorum += reason == "quorum"
-        else:
-            eff_deadline = deadline   # quorum = C ⇒ legacy cutoff, bit-identical
+            # --- quorum-xor-deadline closure (the effective cutoff) ---
+            if c and sched.quorum_frac < 1.0:
+                eff_deadline, reason = quorum_close_time(
+                    tx.latency_s[~tx.lost], c, sched.quorum_frac, deadline)
+                closed_by_quorum += reason == "quorum"
+            else:
+                # quorum = C ⇒ legacy cutoff, bit-identical
+                eff_deadline = deadline
 
-        weights = cohort.agg_weights
-        if sched.corrected and c:
-            arrived = (~tx.lost) & (tx.latency_s <= eff_deadline)
-            weights = _corrected_weights(cohort, arrived)
+            weights = cohort.agg_weights
+            if sched.corrected and c:
+                arrived = (~tx.lost) & (tx.latency_s <= eff_deadline)
+                weights = _corrected_weights(cohort, arrived)
 
-        core.offer_uploads(ids, weights, k, tx, deadline_s=eff_deadline)
-        agg_bytes_peak = max(agg_bytes_peak, agg.state_bytes())
+            core.offer_uploads(ids, weights, k, tx, deadline_s=eff_deadline)
+            agg_bytes_peak = max(agg_bytes_peak, agg.state_bytes())
 
-        aseeds, acoeffs, ars, st = agg.close_round(k)
-        params, use_kernel, apply_s = core.apply_round(
-            params, aseeds, acoeffs, ars, c, st)
-        hist["apply_s"][k] = apply_s
-        if core.digest_mode:
-            downlink_bits += core.close_digest(k, aseeds, acoeffs, ars, st,
-                                               ids, params, use_kernel)
+            aseeds, acoeffs, ars, st = agg.close_round(k)
+            params, use_kernel, apply_s = core.apply_round(
+                params, aseeds, acoeffs, ars, c, st)
+            hist["apply_s"][k] = apply_s
+            if core.digest_mode:
+                downlink_bits += core.close_digest(k, aseeds, acoeffs, ars, st,
+                                                   ids, params, use_kernel)
 
-        # --- cost accounting (legacy formulas, effective deadline) ---
-        async_mode = (cfg.server.max_staleness > 0
-                      and math.isfinite(cfg.server.round_period_s))
-        if c:
-            bits, wall, energy = cm.cohort_round_cost(
-                tx.latency_s, core.codec.bits_per_upload,
-                deadline_s=eff_deadline)
-        else:
-            bits, energy, wall = 0.0, 0.0, cm.t_other
-        if async_mode:
-            wall = cfg.server.round_period_s
+            # --- cost accounting (legacy formulas, effective deadline) ---
+            async_mode = (cfg.server.max_staleness > 0
+                          and math.isfinite(cfg.server.round_period_s))
+            if c:
+                bits, wall, energy = cm.cohort_round_cost(
+                    tx.latency_s, core.codec.bits_per_upload,
+                    deadline_s=eff_deadline)
+            else:
+                bits, energy, wall = 0.0, 0.0, cm.t_other
+            if async_mode:
+                wall = cfg.server.round_period_s
 
-        starts[k] = clock
-        clock += wall
-        closes[k] = clock
+            starts[k] = clock
+            clock += wall
+            closes[k] = clock
 
-        hist["cohort_size"][k] = c
-        hist["applied"][k] = st.applied
-        hist["applied_stale"][k] = st.applied_stale
-        hist["lost_channel"][k] = st.lost_channel
-        hist["dropped_deadline"][k] = st.dropped_deadline
-        hist["dropped_stale"][k] = st.dropped_stale
-        hist["weight_sum"][k] = st.weight_sum
-        hist["cum_bits"][k] = bits
-        hist["cum_downlink_bits"][k] = downlink_bits
-        hist["cum_wall_s"][k] = wall
-        hist["cum_energy_j"][k] = energy
-        _, dl_wall, dl_energy = downlink.round_cost(downlink_bits)
-        hist["cum_downlink_wall_s"][k] = dl_wall
-        hist["cum_downlink_energy_j"][k] = dl_energy
-        if k % cfg.eval_every == 0 or k == K - 1:
-            loss, acc = core.evaluate(params)
-            hist["loss"][k] = float(loss)
-            hist["accuracy"][k] = float(acc)
+            hist["cohort_size"][k] = c
+            hist["applied"][k] = st.applied
+            hist["applied_stale"][k] = st.applied_stale
+            hist["lost_channel"][k] = st.lost_channel
+            hist["dropped_deadline"][k] = st.dropped_deadline
+            hist["dropped_stale"][k] = st.dropped_stale
+            hist["weight_sum"][k] = st.weight_sum
+            hist["cum_bits"][k] = bits
+            hist["cum_downlink_bits"][k] = downlink_bits
+            hist["cum_wall_s"][k] = wall
+            hist["cum_energy_j"][k] = energy
+            _, dl_wall, dl_energy = downlink.round_cost(downlink_bits)
+            hist["cum_downlink_wall_s"][k] = dl_wall
+            hist["cum_downlink_energy_j"][k] = dl_energy
+            if k % cfg.eval_every == 0 or k == K - 1:
+                _evaluate(core, params, hist, k)
 
     makespan = float(clock) if K else 0.0
     extra = dict(scheduler=_scheduler_summary(
@@ -447,129 +457,129 @@ def _run_async(core, init_params, sched: SchedulerConfig) -> dict:
     agg_bytes_peak = 0
 
     for k in range(K):
-        start = pipelined_round_start(k, starts, drains, period, depth)
-        starts[k] = start
-        # params version this round reads: rounds drained by its open
-        v = int(np.searchsorted(drains[:k], start, side="right"))
-        lag[k] = k - v
+        with TraceAnnotation("fed.round", round=k):
+            start = pipelined_round_start(k, starts, drains, period, depth)
+            starts[k] = start
+            # params version this round reads: rounds drained by its open
+            v = int(np.searchsorted(drains[:k], start, side="right"))
+            lag[k] = k - v
 
-        cohort = core.sampler.sample(k)
-        ids = cohort.client_ids
-        c = len(ids)
-        offered_total += c
+            cohort = core.sampler.sample(k)
+            ids = cohort.client_ids
+            c = len(ids)
+            offered_total += c
 
-        if core.digest_mode:
-            # the cohort syncs to x_v — the version it will compute on —
-            # via the bounded log (dense fallback past the window)
-            catchup_bits, _, resyncs = downlink.catch_up_batch(
-                core.client_last[ids], v)
-            downlink_bits = catchup_bits
-            hist["catchup_bits"][k] = catchup_bits
-            hist["dense_resyncs"][k] = resyncs
-        else:
-            downlink_bits = downlink.broadcast()
+            if core.digest_mode:
+                # the cohort syncs to x_v — the version it computes on —
+                # via the bounded log (dense fallback past the window)
+                catchup_bits, _, resyncs = downlink.catch_up_batch(
+                    core.client_last[ids], v)
+                downlink_bits = catchup_bits
+                hist["catchup_bits"][k] = catchup_bits
+                hist["dense_resyncs"][k] = resyncs
+            else:
+                downlink_bits = downlink.broadcast()
 
-        rs_np, seeds_np = core.compute_cohort(versions[v], k, ids)
-        tx = uplink.transmit(rs_np[:c], seeds_np[:c]) if c else None
+            rs_np, seeds_np = core.compute_cohort(versions[v], k, ids)
+            tx = uplink.transmit(rs_np[:c], seeds_np[:c]) if c else None
 
-        # --- closure: quorum over the fresh cohort, xor deadline ---
-        if c:
-            close_lat, reason = quorum_close_time(
-                tx.latency_s[~tx.lost], c, sched.quorum_frac, deadline)
-            closed_by_quorum += reason == "quorum"
-            close_off = t_other + close_lat
-        else:
-            close_off = t_other
-        closes[k] = start + close_off
+            # --- closure: quorum over the fresh cohort, xor deadline ---
+            if c:
+                close_lat, reason = quorum_close_time(
+                    tx.latency_s[~tx.lost], c, sched.quorum_frac, deadline)
+                closed_by_quorum += reason == "quorum"
+                close_off = t_other + close_lat
+            else:
+                close_off = t_other
+            closes[k] = start + close_off
 
-        if c:
-            ontime = (~tx.lost) & (tx.latency_s <= close_lat)
-            late = (~tx.lost) & ~ontime
-            weights = (_corrected_weights(cohort, ontime)
-                       if sched.corrected else cohort.agg_weights)
-            # lost uploads are offered (→ lost_channel), on-time applied
-            for i in np.where(tx.lost)[0]:
-                agg.offer_routed(Upload(
-                    client_id=int(ids[i]), encoded_round=k,
-                    seed=int(tx.seeds[i]), r=tx.r_hat[i],
-                    agg_weight=float(weights[i]),
-                    latency_s=float(tx.latency_s[i]), lost=True), k, 0)
-            for i in np.where(ontime)[0]:
-                agg.offer_routed(Upload(
-                    client_id=int(ids[i]), encoded_round=k,
-                    seed=int(tx.seeds[i]), r=tx.r_hat[i],
-                    agg_weight=float(weights[i]),
-                    latency_s=float(tx.latency_s[i]), lost=False), k, 0)
-            # post-close arrivals park in the waiting queue, original w
-            if late.any():
-                ac.enqueue(CohortBatch(
-                    encoded_round=k,
-                    client_ids=np.asarray(ids[late], np.int64),
-                    seeds=np.asarray(tx.seeds[late], np.uint32),
-                    payloads=np.asarray(tx.r_hat[late], np.float32),
-                    weights=np.asarray(cohort.agg_weights[late], np.float64),
-                    arrival_abs=start + t_other + tx.latency_s[late]))
+            if c:
+                ontime = (~tx.lost) & (tx.latency_s <= close_lat)
+                late = (~tx.lost) & ~ontime
+                weights = (_corrected_weights(cohort, ontime)
+                           if sched.corrected else cohort.agg_weights)
+                # lost uploads are offered (→ lost_channel), on-time applied
+                for i in np.where(tx.lost)[0]:
+                    agg.offer_routed(Upload(
+                        client_id=int(ids[i]), encoded_round=k,
+                        seed=int(tx.seeds[i]), r=tx.r_hat[i],
+                        agg_weight=float(weights[i]),
+                        latency_s=float(tx.latency_s[i]), lost=True), k, 0)
+                for i in np.where(ontime)[0]:
+                    agg.offer_routed(Upload(
+                        client_id=int(ids[i]), encoded_round=k,
+                        seed=int(tx.seeds[i]), r=tx.r_hat[i],
+                        agg_weight=float(weights[i]),
+                        latency_s=float(tx.latency_s[i]), lost=False), k, 0)
+                # post-close arrivals park in the waiting queue, original w
+                if late.any():
+                    ac.enqueue(CohortBatch(
+                        encoded_round=k,
+                        client_ids=np.asarray(ids[late], np.int64),
+                        seeds=np.asarray(tx.seeds[late], np.uint32),
+                        payloads=np.asarray(tx.r_hat[late], np.float32),
+                        weights=np.asarray(cohort.agg_weights[late],
+                                           np.float64),
+                        arrival_abs=start + t_other + tx.latency_s[late]))
 
-        # --- admit queued stragglers whose arrival beat this close ---
-        admitted, dropped = ac.admit_up_to(closes[k], k, window)
-        for _ in range(dropped):
-            agg.note_dropped(k, kind="stale")
-        stale_dropped += dropped
-        for batch, tau in admitted:
-            stale_admitted += len(batch)
-            for i in range(len(batch)):
-                agg.offer_routed(Upload(
-                    client_id=int(batch.client_ids[i]),
-                    encoded_round=batch.encoded_round,
-                    seed=int(batch.seeds[i]), r=batch.payloads[i],
-                    agg_weight=float(batch.weights[i]),
-                    latency_s=float(batch.arrival_abs[i] - starts[
-                        batch.encoded_round]), lost=False), k, tau)
+            # --- admit queued stragglers whose arrival beat this close ---
+            admitted, dropped = ac.admit_up_to(closes[k], k, window)
+            for _ in range(dropped):
+                agg.note_dropped(k, kind="stale")
+            stale_dropped += dropped
+            for batch, tau in admitted:
+                stale_admitted += len(batch)
+                for i in range(len(batch)):
+                    agg.offer_routed(Upload(
+                        client_id=int(batch.client_ids[i]),
+                        encoded_round=batch.encoded_round,
+                        seed=int(batch.seeds[i]), r=batch.payloads[i],
+                        agg_weight=float(batch.weights[i]),
+                        latency_s=float(batch.arrival_abs[i] - starts[
+                            batch.encoded_round]), lost=False), k, tau)
 
-        queue_peak_entries = max(queue_peak_entries, ac.num_entries())
-        queue_peak_bytes = max(queue_peak_bytes, ac.state_bytes())
-        agg_bytes_peak = max(agg_bytes_peak, agg.state_bytes())
+            queue_peak_entries = max(queue_peak_entries, ac.num_entries())
+            queue_peak_bytes = max(queue_peak_bytes, ac.state_bytes())
+            agg_bytes_peak = max(agg_bytes_peak, agg.state_bytes())
 
-        # --- close, sequential apply on the head, digest broadcast ---
-        aseeds, acoeffs, ars, st = agg.close_round(k)
-        head, use_kernel, apply_s = core.apply_round(
-            head, aseeds, acoeffs, ars, c, st)
-        hist["apply_s"][k] = apply_s
-        versions[k + 1] = head
-        for old in [key for key in versions if key < k + 2 - depth]:
-            del versions[old]
-        if core.digest_mode:
-            downlink_bits += core.close_digest(k, aseeds, acoeffs, ars, st,
-                                               ids, head, use_kernel)
+            # --- close, sequential apply on the head, digest broadcast ---
+            aseeds, acoeffs, ars, st = agg.close_round(k)
+            head, use_kernel, apply_s = core.apply_round(
+                head, aseeds, acoeffs, ars, c, st)
+            hist["apply_s"][k] = apply_s
+            versions[k + 1] = head
+            for old in [key for key in versions if key < k + 2 - depth]:
+                del versions[old]
+            if core.digest_mode:
+                downlink_bits += core.close_digest(k, aseeds, acoeffs, ars, st,
+                                                   ids, head, use_kernel)
 
-        # drain = close (+ the downlink rides its own priced channel);
-        # monotone — the digest log is append-ordered
-        drains[k] = max(closes[k], drains[k - 1]) if k else closes[k]
+            # drain = close (+ the downlink rides its own priced channel);
+            # monotone — the digest log is append-ordered
+            drains[k] = max(closes[k], drains[k - 1]) if k else closes[k]
 
-        # --- accounting: modeled wall = drain increments (makespan) ---
-        if c:
-            air = np.clip(tx.latency_s - base_lat, 0.0, None)
-            energy = float(p_tx * air.sum())
-        else:
-            energy = 0.0
-        hist["cohort_size"][k] = c
-        hist["applied"][k] = st.applied
-        hist["applied_stale"][k] = st.applied_stale
-        hist["lost_channel"][k] = st.lost_channel
-        hist["dropped_deadline"][k] = st.dropped_deadline
-        hist["dropped_stale"][k] = st.dropped_stale
-        hist["weight_sum"][k] = st.weight_sum
-        hist["cum_bits"][k] = float(c * bits_up)
-        hist["cum_downlink_bits"][k] = downlink_bits
-        hist["cum_wall_s"][k] = drains[k] - (drains[k - 1] if k else 0.0)
-        hist["cum_energy_j"][k] = energy
-        _, dl_wall, dl_energy = downlink.round_cost(downlink_bits)
-        hist["cum_downlink_wall_s"][k] = dl_wall
-        hist["cum_downlink_energy_j"][k] = dl_energy
-        if k % cfg.eval_every == 0 or k == K - 1:
-            loss, acc = core.evaluate(head)
-            hist["loss"][k] = float(loss)
-            hist["accuracy"][k] = float(acc)
+            # --- accounting: modeled wall = drain increments (makespan) ---
+            if c:
+                air = np.clip(tx.latency_s - base_lat, 0.0, None)
+                energy = float(p_tx * air.sum())
+            else:
+                energy = 0.0
+            hist["cohort_size"][k] = c
+            hist["applied"][k] = st.applied
+            hist["applied_stale"][k] = st.applied_stale
+            hist["lost_channel"][k] = st.lost_channel
+            hist["dropped_deadline"][k] = st.dropped_deadline
+            hist["dropped_stale"][k] = st.dropped_stale
+            hist["weight_sum"][k] = st.weight_sum
+            hist["cum_bits"][k] = float(c * bits_up)
+            hist["cum_downlink_bits"][k] = downlink_bits
+            hist["cum_wall_s"][k] = drains[k] - (drains[k - 1] if k else 0.0)
+            hist["cum_energy_j"][k] = energy
+            _, dl_wall, dl_energy = downlink.round_cost(downlink_bits)
+            hist["cum_downlink_wall_s"][k] = dl_wall
+            hist["cum_downlink_energy_j"][k] = dl_energy
+            if k % cfg.eval_every == 0 or k == K - 1:
+                _evaluate(core, head, hist, k)
 
     makespan = float(drains[-1]) if K else 0.0
     extra = dict(scheduler=_scheduler_summary(

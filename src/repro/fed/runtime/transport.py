@@ -52,6 +52,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.fed.costmodel import (
     DIGEST_HEADER_BITS,
@@ -395,15 +396,17 @@ class UplinkChannel:
         (``tests/test_statistical.py``) without O(C) interpreter
         round-trips per round.
         """
-        c = len(seeds)
-        rs = np.asarray(rs, np.float32).reshape(c, -1)
-        blob = self.fmt.encode_batch(rs, np.asarray(seeds, np.uint32))
-        r_hat, seeds_hat = self.fmt.decode_batch(blob, c)
-        latency = self.cm.per_client_upload_seconds(self.fmt.bits_per_upload, c)
-        lost = self.cm.per_client_drops(c)
-        return TransmitResult(
-            r_hat=r_hat, seeds=seeds_hat, latency_s=latency, lost=lost,
-            payload_bytes=c * self.fmt.bytes_per_upload)
+        with TraceAnnotation("fed.transmit"):
+            c = len(seeds)
+            rs = np.asarray(rs, np.float32).reshape(c, -1)
+            blob = self.fmt.encode_batch(rs, np.asarray(seeds, np.uint32))
+            r_hat, seeds_hat = self.fmt.decode_batch(blob, c)
+            latency = self.cm.per_client_upload_seconds(
+                self.fmt.bits_per_upload, c)
+            lost = self.cm.per_client_drops(c)
+            return TransmitResult(
+                r_hat=r_hat, seeds=seeds_hat, latency_s=latency, lost=lost,
+                payload_bytes=c * self.fmt.bytes_per_upload)
 
 
 # ---------------------------------------------------------------------------
@@ -675,29 +678,31 @@ class DownlinkChannel:
         the digest catch-up was the engine's last per-client Python
         loop, and it is what a 10⁵-member cohort stalls on.
         """
-        rounds = np.asarray(client_rounds, np.int64)
-        if self.mode == "dense" or len(rounds) == 0:
-            return 0, 0, 0
-        log = self.log
-        target = min(int(target_round), log.next_round)
-        behind = rounds < target
-        if not behind.any():
-            return 0, 0, 0
-        lo = max(0, log.next_round - log.window)
-        dense = behind & (rounds < lo)
-        digest = behind & ~dense
-        n_dense = int(dense.sum())
-        n_digest = int(digest.sum())
-        bits = n_dense * self.dense_bits
-        if n_digest:
-            pref = np.asarray(
-                [log._prefix[r] for r in range(lo, log.next_round + 1)],
-                np.int64)
-            bits += int(np.sum(pref[target - lo] - pref[rounds[digest] - lo]))
-        self.total_bits += bits
-        self.catchup_bits += bits
-        self.dense_resyncs += n_dense
-        return bits, n_digest, n_dense
+        with TraceAnnotation("fed.catch_up"):
+            rounds = np.asarray(client_rounds, np.int64)
+            if self.mode == "dense" or len(rounds) == 0:
+                return 0, 0, 0
+            log = self.log
+            target = min(int(target_round), log.next_round)
+            behind = rounds < target
+            if not behind.any():
+                return 0, 0, 0
+            lo = max(0, log.next_round - log.window)
+            dense = behind & (rounds < lo)
+            digest = behind & ~dense
+            n_dense = int(dense.sum())
+            n_digest = int(digest.sum())
+            bits = n_dense * self.dense_bits
+            if n_digest:
+                pref = np.asarray(
+                    [log._prefix[r] for r in range(lo, log.next_round + 1)],
+                    np.int64)
+                bits += int(np.sum(pref[target - lo]
+                                   - pref[rounds[digest] - lo]))
+            self.total_bits += bits
+            self.catchup_bits += bits
+            self.dense_resyncs += n_dense
+            return bits, n_digest, n_dense
 
     def round_cost(self, bits: float) -> tuple[float, float, float]:
         """(bits, wall_s, energy_J) of one round's downlink traffic —

@@ -196,16 +196,19 @@ def test_full_participation_reproduces_simulation_bitforbit():
 def test_event_driven_partial_participation_descends():
     from repro.models.mlp_classifier import init_mlp
 
+    from repro.fed.runtime.engine import cohort_sampler
+    from repro.fed.runtime.sampling import sampling_diagnostic
+
     clients, xte, yte = _digits(8)
-    h = run_federation(
-        RuntimeConfig(rounds=40, population=500, participation=0.08,
-                      eval_every=39),
-        init_mlp(), clients, xte, yte)
+    cfg = RuntimeConfig(rounds=40, population=500, participation=0.08,
+                        eval_every=39)
+    h = run_federation(cfg, init_mlp(), clients, xte, yte)
     assert not h["fused_path"]
     evals = ~np.isnan(h["loss"])
     assert h["loss"][evals][-1] < h["loss"][evals][0]
     assert np.all(h["cohort_size"] == 40)
-    assert h["sampling_diagnostic"]["estimate_rel_err"] < 0.1
+    diag = sampling_diagnostic(cohort_sampler(cfg, clients), rounds=160)
+    assert diag["estimate_rel_err"] < 0.1
     # Σwᵢ per round should hover around 1 (IPW correctness)
     assert abs(np.mean(h["weight_sum"]) - 1.0) < 0.05
 

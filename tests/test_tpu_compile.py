@@ -14,6 +14,9 @@ TPU library admits one process at a time, and every pytest worker
 imports this file.
 """
 import functools
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,3 +121,28 @@ def test_every_tuner_tile_compiles_for_v5e(one_chip, block, dtype):
     text = jax.jit(close).lower(x, seeds, rs).compile().as_text()
     assert text.count("tpu_custom_call") == 1, block
     assert "callback" not in text, block
+
+
+def test_close_kernel_keeps_its_name_under_any_jit_or_scope(one_chip):
+    """The benchmark finds the fused close in a device trace by the
+    custom call's HLO name (``close_kernel`` of the close cell's
+    traffic); the name stays under a jit of another name and inside a
+    named scope."""
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "benchmarks", "chip", "traffic", "close.c256.json")
+    with open(path) as f:
+        kernel = re.compile(json.load(f)["close_kernel"])
+    tree = _tree(one_chip, MLP, (320, 960))
+    rs = jax.ShapeDtypeStruct((COHORT, 1), jnp.float32, sharding=one_chip)
+    seeds = jax.ShapeDtypeStruct((COHORT,), jnp.uint32, sharding=one_chip)
+    close = _fused(Distribution.RADEMACHER, 1)
+
+    def close_step(params, rs, seeds):
+        with jax.named_scope("fed.close"):
+            return close(params, rs, seeds)
+
+    text = jax.jit(close_step).lower(tree, rs, seeds).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == len(tree)
+    assert all(kernel.search(c) for c in calls), calls
