@@ -483,6 +483,7 @@ class EngineCore:
         self.chunk_payloads = chunk_payloads
 
         # ---- jitted server applies (bucketed shapes) ----
+        self.apply_stats: dict = {}     # stats of the fed.apply_round span
         if proto.name == "fedscalar":
             @jax.jit
             def apply_fori(params, rs, seeds, weights):
@@ -502,16 +503,21 @@ class EngineCore:
             # untuned applies agree to the bit; DESIGN §11).
             fused_params = None
             if cfg.projection_mode == "fused_kernel":
+                from repro.kernels.ops import fused_tiling, shape_2d
                 from repro.kernels.tune import cached_fused_params
                 lead = max(jax.tree_util.tree_leaves(init_params),
                            key=lambda x: x.size, default=None)
                 if lead is not None and lead.ndim:
-                    x2 = lead.reshape(-1, lead.shape[-1]) if lead.ndim > 1 \
-                        else lead.reshape(1, -1)
                     fused_params = cached_fused_params(
-                        x2.shape[0], x2.shape[1], cfg.cohort_size(),
+                        *shape_2d(lead.shape), cfg.cohort_size(),
                         cfg.num_projections,
                         cfg.resolved_distribution().value)
+                if cfg.mesh_shape is None:
+                    # The fused close's tiling of this tree, for the
+                    # apply span: worked out once here, not per round.
+                    block = (fused_params or {}).get("block")
+                    self.apply_stats = fused_tiling(
+                        init_params, tuple(block) if block else None)
 
             @jax.jit
             def apply_fused(params, rs, seeds, weights):
@@ -622,7 +628,8 @@ class EngineCore:
         for both drivers, and ``method`` is what the digest replay must
         pin (it threads opaquely to :meth:`close_digest`).
         """
-        with TraceAnnotation("fed.apply_round", rows=len(aseeds)):
+        with TraceAnnotation("fed.apply_round", rows=len(aseeds),
+                             **self.apply_stats):
             a = len(aseeds)
             use_kernel: bool | str = False
             apply_s = 0.0

@@ -24,6 +24,7 @@ in-kernel).
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -35,18 +36,22 @@ from repro.core.projection import ProjectionMode, _proj_seed, leaf_layout
 from repro.kernels.qsgd_quant import qsgd_kernel_call
 from repro.kernels.reconstruct_apply import (
     DEFAULT_FUSED_BLOCK,
+    fused_plan,
     fused_reconstruct_apply,
 )
 from repro.kernels.seeded_projection import projection_blocks_kernel_call
 from repro.kernels.seeded_reconstruct import reconstruct_kernel_call
 
 __all__ = [
+    "shape_2d",
+    "as_2d",
     "as_blocked_2d",
     "leaf_block_bounds",
     "fold_upload_weights",
     "project_tree_kernel",
     "server_update_kernel",
     "server_update_fused",
+    "fused_tiling",
     "qsgd_roundtrip_kernel",
 ]
 
@@ -56,14 +61,22 @@ def _pick_block(rows: int, cols: int) -> tuple:
     return br, bc
 
 
+def shape_2d(shape) -> tuple[int, int]:
+    """A leaf's shape → its (leading dims, last dim) matrix shape;
+    scalars and vectors become one row."""
+    if len(shape) < 2:
+        return 1, int(math.prod(shape))
+    return int(math.prod(shape[:-1])), int(shape[-1])
+
+
+def as_2d(leaf):
+    """leaf → its :func:`shape_2d` matrix view."""
+    return leaf.reshape(shape_2d(leaf.shape))
+
+
 def as_blocked_2d(leaf: jax.Array):
     """leaf → (padded 2-D view, block, original (rows, cols))."""
-    if leaf.ndim == 0:
-        x = leaf.reshape(1, 1)
-    elif leaf.ndim == 1:
-        x = leaf.reshape(1, -1)
-    else:
-        x = leaf.reshape(-1, leaf.shape[-1])
+    x = as_2d(leaf)
     rows, cols = x.shape
     br, bc = _pick_block(rows, cols)
     pr = (-rows) % br
@@ -198,14 +211,6 @@ def server_update_kernel(
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def _pick_fused_block(rows: int, cols: int) -> tuple:
-    """Largest fused tile ≤ DEFAULT_FUSED_BLOCK that the padded leaf fits."""
-    fbr, fbc = DEFAULT_FUSED_BLOCK
-    br = min(fbr, -(-rows // 8) * 8)
-    bc = min(fbc, -(-cols // 128) * 128)
-    return br, bc
-
-
 def server_update_fused(
     params: Any,
     rs: jax.Array,        # (N,), (N, 1) or (N, k) uploaded scalars
@@ -228,8 +233,8 @@ def server_update_fused(
     to ``server_update_kernel``/``server_update_ref``; the fused path's
     own bitwise oracle is ``ref.server_update_fused_ref``.  ``block``/
     ``row_slab`` take autotuned winners (``kernels.tune``); both are
-    bits-invariant.  The mirror path (CPU) runs leaves unpadded; the
-    Pallas path pads to the tile like the other kernels (exact).
+    bits-invariant.  Leaves go in as their 2-D views, unpadded: the
+    Pallas dispatch tiles each one itself (``fused_plan``).
     """
     rs, scale = fold_upload_weights(rs, server_lr, weights, mode, block_weights)
     k = rs.shape[1]
@@ -241,28 +246,29 @@ def server_update_fused(
     masked = mode == ProjectionMode.BLOCK and k > 1
     out = []
     for ll, leaf in zip(layout, leaves):
-        if leaf.ndim == 0:
-            x2d = leaf.reshape(1, 1)
-        elif leaf.ndim == 1:
-            x2d = leaf.reshape(1, -1)
-        else:
-            x2d = leaf.reshape(-1, leaf.shape[-1])
-        rows, cols = x2d.shape
-        blk = block
-        if use_pallas:
-            blk = blk or _pick_fused_block(rows, cols)
-            pr = (-rows) % blk[0]
-            pc = (-cols) % blk[1]
-            if pr or pc:
-                x2d = jnp.pad(x2d, ((0, pr), (0, pc)))
         lo, hi = leaf_block_bounds(ll.offset, ll.size, total, k, mode)
         y = fused_reconstruct_apply(
-            x2d, seeds, rs, ll.tag, scale, _dist_name(distribution),
-            block=blk or DEFAULT_FUSED_BLOCK, lo=jnp.asarray(lo, jnp.float32),
-            hi=jnp.asarray(hi, jnp.float32), orig_cols=cols, masked=masked,
+            as_2d(leaf), seeds, rs, ll.tag, scale, _dist_name(distribution),
+            block=block or DEFAULT_FUSED_BLOCK, lo=jnp.asarray(lo, jnp.float32),
+            hi=jnp.asarray(hi, jnp.float32), masked=masked,
             use_pallas=use_pallas, interpret=interpret, row_slab=row_slab)
-        out.append(y[:rows, :cols].reshape(leaf.shape))
+        out.append(y.reshape(leaf.shape))
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def fused_tiling(params: Any, block: tuple | None = None,
+                 use_pallas: bool | None = None) -> dict:
+    """How :func:`server_update_fused` tiles ``params`` (arrays or
+    shapes): ``lane_rows_leaves``, the leaves the Pallas kernel closes
+    with their rows along lanes, and ``pad_elements``, the elements it
+    computes and throws away per client.  The jnp mirror (the default
+    off the TPU) tiles nothing: both are 0 there."""
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    plans = [fused_plan(*shape_2d(leaf.shape), block or DEFAULT_FUSED_BLOCK)
+             for leaf in jax.tree_util.tree_leaves(params)] if use_pallas else []
+    return {"lane_rows_leaves": sum(p.lanes_rows for p in plans),
+            "pad_elements": sum(p.pad for p in plans)}
 
 
 def qsgd_roundtrip_kernel(

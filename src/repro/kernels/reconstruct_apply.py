@@ -52,14 +52,30 @@ per (client, row), leaving one mixer round per element.  The projection
 kernel shares the same factored generator, so uplink encode and
 downlink decode literally run one generator (DESIGN §11).
 
-Shapes/dtypes: x2d is any 2-D float matrix (block-aligned only for the
-Pallas path); seeds are uint32 ``(N,)`` **round** seeds (unfolded); rs
-is float32 ``(N, k)`` with every aggregation weight pre-folded; block
-bounds are leaf-local flat float32 ``(k,)`` as in the other kernels.
+Orientation (``fused_plan``).  The Pallas kernel tiles a leaf in one of
+two orientations, chosen from its shape.  Where the rows are a multiple
+of 128 and the columns of 8 — every large matrix of a transformer — it
+runs over tiles of xᵀ: the leaf's rows lie along the vector lanes and
+its columns along the sublanes.  The hoisted row state is then a
+``(1, tr)`` vector that every sublane shares, where the natural
+orientation's ``(tr, 1)`` column costs one lane-replicated vreg per 8
+rows, and the column tile is a multiple of 8 that divides the columns,
+so a 960- or 320-column leaf computes no pad columns.  Any other leaf
+(vectors, short norms, the paper MLP) is tiled as it lies and padded to
+its tile.  Orientation, pad and the transposes around the call live in
+the Pallas dispatch; callers pass the unpadded 2-D view.  Values are a
+function of (row, col) alone and the chunk fold is elementwise, so both
+orientations give the same bits.
+
+Shapes/dtypes: x2d is any 2-D float matrix; seeds are uint32 ``(N,)``
+**round** seeds (unfolded); rs is float32 ``(N, k)`` with every
+aggregation weight pre-folded; block bounds are leaf-local flat float32
+``(k,)`` as in the other kernels.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -75,15 +91,17 @@ from repro.kernels.common import (
     tile_from_state,
 )
 
-__all__ = ["fused_reconstruct_apply", "FUSED_CHUNK", "DEFAULT_FUSED_BLOCK"]
+__all__ = ["fused_reconstruct_apply", "fused_plan", "FusedPlan",
+           "FUSED_CHUNK", "DEFAULT_FUSED_BLOCK"]
 
 # Clients regenerated per chunk partial.  Pinned: part of the numeric
 # spec (see module docstring), NOT autotunable.
 FUSED_CHUNK = 16
 
-# Default Pallas tile.  Smaller than the two-kernel default because the
-# kernel holds a (FUSED_CHUNK, br, bc) product scratch in VMEM:
-# 16·128·256·4 B = 2 MiB, comfortably under budget with x, acc and y.
+# Default Pallas tile budget: at most br rows by bc columns of the leaf
+# per grid step (``fused_plan``).  Smaller than the two-kernel default
+# because the kernel holds a float32 product scratch of FUSED_CHUNK
+# tiles in VMEM: 16·128·256·4 B = 2 MiB, under budget with x, acc and y.
 DEFAULT_FUSED_BLOCK = (128, 256)
 
 
@@ -143,34 +161,85 @@ def _chunk_partial(folded, rr, row, col, distribution, mask):
 # ---------------------------------------------------------------------------
 
 
+class FusedPlan(NamedTuple):
+    """How the Pallas kernel tiles one ``(rows, cols)`` leaf.
+
+    ``lanes_rows``: the kernel works on tiles of xᵀ, the leaf's rows
+    along lanes and its columns along sublanes; otherwise it tiles x as
+    it lies.  ``tile`` is the ``(rows, cols)`` of the leaf one grid step
+    covers, in either orientation.  ``pad`` is the elements the kernel
+    computes and throws away, per client.
+    """
+    lanes_rows: bool
+    tile: tuple
+    pad: int
+
+
+def _largest_tile(n: int, step: int, cap: int) -> int:
+    """Largest multiple of ``step`` that divides ``n`` and is ≤ ``cap``
+    (``step`` itself at least; ``n`` is a multiple of it)."""
+    for t in range(max(cap, step) // step * step, step, -step):
+        if n % t == 0:
+            return t
+    return step
+
+
+def fused_plan(rows: int, cols: int,
+               block: tuple = DEFAULT_FUSED_BLOCK) -> FusedPlan:
+    """The Pallas tiling of a ``(rows, cols)`` leaf under the ``block``
+    budget ``(br, bc)`` (at most br rows by bc columns per tile).
+
+    Where the rows tile the lanes (rows % 128 == 0) and the columns the
+    sublanes (cols % 8 == 0), the kernel puts rows along lanes: the
+    hoisted per-row state is then a ``(1, tr)`` vector shared by every
+    sublane, and the column tile is a multiple of 8 that divides
+    ``cols``, so nothing is padded.  Any other leaf (``(1, n)`` vectors,
+    short matrices) is tiled as it lies and padded up to its tile.
+    """
+    br, bc = block
+    if rows % 128 == 0 and cols % 8 == 0:
+        return FusedPlan(True, (_largest_tile(rows, 128, br),
+                                _largest_tile(cols, 8, bc)), 0)
+    tr = min(br, -(-rows // 8) * 8)
+    tc = min(bc, -(-cols // 128) * 128)
+    pad = -(-rows // tr) * tr * (-(-cols // tc) * tc) - rows * cols
+    return FusedPlan(False, (tr, tc), pad)
+
+
 def _fused_kernel(seeds_ref, rs_ref, lo_ref, hi_ref, offs_ref,
                   x_ref, o_ref, acc_ref, prod_ref, *, distribution: str,
                   num_chunks: int, num_blocks: int, masked: bool,
-                  block: tuple, leaf_tag: int, orig_cols: int,
-                  padded_cohort: int):
+                  tile: tuple, lanes_rows: bool, leaf_tag: int,
+                  orig_cols: int, padded_cohort: int):
     pi = pl.program_id(0)
     pj = pl.program_id(1)
     pb = pl.program_id(2)
     pc = pl.program_id(3)
-    br, bc = block
+    tr, tc = tile
     row_offset = offs_ref[0]
     col_offset = offs_ref[1]
-    # (br, 1) × (1, bc) coordinate vectors: the factored chain touches
-    # rows only until the last mixer round, so stage 2 runs on a column.
-    row = (jax.lax.broadcasted_iota(jnp.uint32, (br, 1), 0)
-           + row_offset + pi.astype(jnp.uint32) * jnp.uint32(br))
-    col = (jax.lax.broadcasted_iota(jnp.uint32, (1, bc), 1)
-           + col_offset + pj.astype(jnp.uint32) * jnp.uint32(bc))
+    # Coordinate vectors that broadcast to the kernel's tile: the
+    # factored chain touches rows only until the last mixer round, so
+    # its stage 2 runs on the row vector alone.  Rows along lanes make
+    # that vector (1, tr), one sublane-broadcast row of vregs; as the
+    # leaf lies it is a (tr, 1) column, lane-replicated and 8× dearer.
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.uint32)
+    if lanes_rows:
+        row, col = iota((1, tr), 1), iota((tc, 1), 0)
+    else:
+        row, col = iota((tr, 1), 0), iota((1, tc), 1)
+    row = row + row_offset + pi.astype(jnp.uint32) * jnp.uint32(tr)
+    col = col + col_offset + pj.astype(jnp.uint32) * jnp.uint32(tc)
 
     @pl.when(jnp.logical_and(pb == 0, pc == 0))
     def _():
-        acc_ref[...] = jnp.zeros((br, bc), jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     base = pc * FUSED_CHUNK
     salt = jnp.uint32(PROJ_SALT) + pb.astype(jnp.uint32)
 
     def chunk_sum(mask):
-        # Materialize the chunk's (cb, br, bc) products in VMEM, then
+        # Materialize the chunk's (cb, ·, ·) products in VMEM, then
         # fold them: the store/load boundary is what keeps the multiply
         # out of the adds (the mirror's optimization barrier), and the
         # fold is the spec's own association (``fold_chunk``).
@@ -193,11 +262,12 @@ def _fused_kernel(seeds_ref, rs_ref, lo_ref, hi_ref, offs_ref,
     if not masked:
         chunk_sum(None)
     else:
-        # Same provably-empty-intersection skip as the two-kernel path.
+        # Same provably-empty-intersection skip as the two-kernel path,
+        # over the flat range of the tile's tr rows.
         r0 = (row_offset.astype(jnp.int32).astype(jnp.float32)
-              + pi.astype(jnp.float32) * jnp.float32(br))
+              + pi.astype(jnp.float32) * jnp.float32(tr))
         tile_lo = r0 * jnp.float32(orig_cols)
-        tile_hi = (r0 + jnp.float32(br - 1) + 1.0) * jnp.float32(orig_cols)
+        tile_hi = (r0 + jnp.float32(tr - 1) + 1.0) * jnp.float32(orig_cols)
         overlap = jnp.logical_and(tile_lo < hi_ref[pb], tile_hi > lo_ref[pb])
 
         @pl.when(overlap)
@@ -215,33 +285,46 @@ def _fused_kernel(seeds_ref, rs_ref, lo_ref, hi_ref, offs_ref,
 def _fused_pallas(x2d, seeds, rs, leaf_tag, distribution, block,
                   row_offset, col_offset, lo, hi, orig_cols, masked,
                   interpret):
+    """The kernel over one leaf: orientation, pad and slice from
+    :func:`fused_plan`, so callers pass the leaf as it is."""
     rows, cols = x2d.shape
-    br, bc = block
-    assert rows % br == 0 and cols % bc == 0, (x2d.shape, block)
-    n, k = rs.shape
+    plan = fused_plan(rows, cols, block)
+    tr, tc = plan.tile
+    if plan.lanes_rows:
+        xk, kblock = x2d.T, (tc, tr)
+    else:
+        pr, pc = (-rows) % tr, (-cols) % tc
+        xk = jnp.pad(x2d, ((0, pr), (0, pc))) if pr or pc else x2d
+        kblock = (tr, tc)
+
+    def index(i, j, b, c):      # grid (row tile, col tile, block, chunk)
+        return (j, i) if plan.lanes_rows else (i, j)
+
+    k = rs.shape[1]
     seeds, rs, num_chunks = _pad_cohort(seeds, rs)
     padded_cohort = num_chunks * FUSED_CHUNK
     offs = jnp.stack([jnp.asarray(row_offset, jnp.uint32),
                       jnp.asarray(col_offset, jnp.uint32)])
     kern = functools.partial(
         _fused_kernel, distribution=distribution, num_chunks=num_chunks,
-        num_blocks=k, masked=masked, block=block, leaf_tag=leaf_tag,
-        orig_cols=orig_cols, padded_cohort=padded_cohort)
+        num_blocks=k, masked=masked, tile=plan.tile,
+        lanes_rows=plan.lanes_rows, leaf_tag=leaf_tag, orig_cols=orig_cols,
+        padded_cohort=padded_cohort)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kern,
-        grid=(rows // br, cols // bc, k, num_chunks),
-        in_specs=[smem, smem, smem, smem, smem,
-                  pl.BlockSpec((br, bc), lambda i, j, b, c: (i, j))],
-        out_specs=pl.BlockSpec((br, bc), lambda i, j, b, c: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), x2d.dtype),
-        scratch_shapes=[pltpu.VMEM((br, bc), jnp.float32),
-                        pltpu.VMEM((FUSED_CHUNK, br, bc), jnp.float32)],
+        grid=(-(-rows // tr), -(-cols // tc), k, num_chunks),
+        in_specs=[smem, smem, smem, smem, smem, pl.BlockSpec(kblock, index)],
+        out_specs=pl.BlockSpec(kblock, index),
+        out_shape=jax.ShapeDtypeStruct(xk.shape, x2d.dtype),
+        scratch_shapes=[pltpu.VMEM(kblock, jnp.float32),
+                        pltpu.VMEM((FUSED_CHUNK,) + kblock, jnp.float32)],
         interpret=interpret,
         # The compiled custom call takes this name under any enclosing
         # jit or named scope; the benchmark finds the kernel by it.
         name="apply_fused",
-    )(seeds, rs.T.reshape(-1), lo, hi, offs, x2d)
+    )(seeds, rs.T.reshape(-1), lo, hi, offs, xk)
+    return y.T if plan.lanes_rows else y[:rows, :cols]
 
 
 # ---------------------------------------------------------------------------
@@ -343,7 +426,8 @@ def fused_reconstruct_apply(
     mirror lowers the *same* chunked spec through XLA directly, so the
     two are bit-identical and the differential suite pins both).
     ``block`` (Pallas) and ``row_slab`` (mirror) are the autotunable,
-    bits-invariant performance knobs; FUSED_CHUNK is not one.
+    bits-invariant performance knobs; FUSED_CHUNK is not one.  ``x2d``
+    may have any shape: the Pallas path tiles it by :func:`fused_plan`.
 
     ``row_offset``/``col_offset`` may be Python ints or traced uint32
     scalars — the mesh-sharded server passes ``shard_ordinal``-derived

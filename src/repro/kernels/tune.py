@@ -3,7 +3,8 @@
 The fused path has exactly two performance knobs, both proven
 bits-invariant (``reconstruct_apply`` module docstring):
 
-* Pallas ``(br, bc)`` tile shape — VMEM working set vs grid overhead;
+* Pallas ``(br, bc)`` tile budget — VMEM working set vs grid overhead;
+  ``reconstruct_apply.fused_plan`` fits it to each leaf's orientation;
 * the jnp mirror's ``row_slab`` height — L1/L2 residency of the
   (slab × cols) contribution tensor on CPU.
 
@@ -39,6 +40,7 @@ import numpy as np
 from repro.kernels.reconstruct_apply import (
     DEFAULT_FUSED_BLOCK,
     FUSED_CHUNK,
+    fused_plan,
     fused_reconstruct_apply,
 )
 
@@ -109,13 +111,14 @@ def _store(path: str, cache: dict) -> None:
 def _candidates(backend: str, rows: int, cols: int,
                 cohort: int = FUSED_CHUNK) -> list[dict]:
     if backend == "tpu":
-        cands = [{"impl": "pallas", "block": list(b), "row_slab": None}
-                 for b in PALLAS_BLOCKS
-                 if rows % b[0] == 0 and cols % b[1] == 0]
-        if not cands:
-            cands = [{"impl": "pallas",
-                      "block": list(DEFAULT_FUSED_BLOCK), "row_slab": None}]
-        return cands
+        # A block is a budget that ``fused_plan`` fits to the leaf, in
+        # its orientation; blocks that give the same tiling are one
+        # candidate.
+        plans = {}
+        for b in PALLAS_BLOCKS:
+            plans.setdefault(fused_plan(rows, cols, b), b)
+        return [{"impl": "pallas", "block": list(b), "row_slab": None}
+                for b in plans.values()]
     # CPU (and any non-TPU backend): the mirror is the serving path —
     # interpret-mode Pallas is a conformance vehicle, not a candidate.
     chunks = max(1, cohort_bucket(cohort) // FUSED_CHUNK)

@@ -322,35 +322,6 @@ def _local_reconstruct_kernel(x_local, seeds, rs, scale, leaf_tag,
     return y[:rl, :cl]
 
 
-def _local_reconstruct_fused(x_local, seeds, rs, scale, leaf_tag,
-                             row_offset, col_offset, distribution,
-                             lo, hi, orig_cols, masked, use_pallas):
-    """Fused reconstruct+apply local body (DESIGN §11).
-
-    The megakernel's chunked numeric spec is a pure function of global
-    (row, col), so the shard offsets compose exactly as they do for the
-    two-kernel path: any shard layout concatenates bit-identically to
-    the single-device fused call (``tests/test_kernel_differential.py``).
-    """
-    from repro.kernels.ops import _pick_fused_block
-    from repro.kernels.reconstruct_apply import fused_reconstruct_apply
-
-    rl, cl = x_local.shape
-    if use_pallas:
-        br, bc = _pick_fused_block(rl, cl)
-        pr, pc = (-rl) % br, (-cl) % bc
-        xp = jnp.pad(x_local, ((0, pr), (0, pc))) if pr or pc else x_local
-        y = fused_reconstruct_apply(
-            xp, seeds, rs, leaf_tag, scale, distribution, block=(br, bc),
-            row_offset=row_offset, col_offset=col_offset, lo=lo, hi=hi,
-            orig_cols=orig_cols, masked=masked, use_pallas=True)
-        return y[:rl, :cl]
-    return fused_reconstruct_apply(
-        x_local, seeds, rs, leaf_tag, scale, distribution,
-        row_offset=row_offset, col_offset=col_offset, lo=lo, hi=hi,
-        orig_cols=orig_cols, masked=masked, use_pallas=False)
-
-
 def _local_project_kernel(x_local, seeds, leaf_tag, row_offset, col_offset,
                           distribution, lo, hi, orig_cols, masked):
     from repro.kernels.ops import _pick_block
@@ -418,6 +389,7 @@ def sharded_apply_blocks(
     either way, DESIGN §11).
     """
     from repro.kernels.ops import fold_upload_weights
+    from repro.kernels.reconstruct_apply import fused_reconstruct_apply
 
     rs, scale = fold_upload_weights(rs, server_lr, weights, mode, block_weights)
     k = rs.shape[1]
@@ -434,9 +406,15 @@ def sharded_apply_blocks(
         for ls, (lo, hi), xl in zip(plan.leaves, bounds, xs):
             ro, co = _offsets(ls, s)
             if use_fused:
-                out.append(_local_reconstruct_fused(
-                    xl, seeds, rs, scale, ls.layout.tag, ro, co, dist,
-                    lo, hi, ls.layout.cols, masked, use_pallas=use_kernel))
+                # The fused spec is a pure function of global (row, col),
+                # so any shard layout concatenates bit-identically to the
+                # single-device close (tests/test_kernel_differential.py);
+                # the Pallas dispatch tiles the unpadded shard itself.
+                out.append(fused_reconstruct_apply(
+                    xl, seeds, rs, ls.layout.tag, scale, dist,
+                    row_offset=ro, col_offset=co, lo=lo, hi=hi,
+                    orig_cols=ls.layout.cols, masked=masked,
+                    use_pallas=use_kernel))
                 continue
             body = _local_reconstruct_kernel if use_kernel \
                 else local_reconstruct_2d

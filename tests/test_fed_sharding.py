@@ -117,6 +117,31 @@ def test_sharded_fused_apply_matches_single_device(fed_mesh, dist, k, mode):
         assert np.array_equal(a, b)
 
 
+@pytest.mark.parametrize("k", [1, 4])
+def test_sharded_fused_pallas_rows_along_lanes(fed_mesh_single, k):
+    """The mesh's fused local body on the Pallas path (interpret mode)
+    hands the kernel its unpadded shard: a 320-column leaf closes
+    rows-along-lanes, bit for bit with the single-device mirror.  (The
+    offsets' composition across shards is pinned on the kernel itself,
+    ``test_fused_rows_along_lanes_offsets_bit_identical``.)"""
+    from repro.kernels.reconstruct_apply import fused_plan
+
+    rng = np.random.RandomState(4)
+    params = {"w": jnp.asarray(rng.randn(256, 320), jnp.float32)}
+    assert fused_plan(256, 320).lanes_rows
+    n = 20
+    seeds = jnp.asarray(rng.randint(0, 2**32, n, dtype=np.uint32))
+    rs = jnp.asarray(rng.randn(n, k).astype(np.float32))
+    mode = ProjectionMode.BLOCK if k > 1 else ProjectionMode.FULL
+    mesh = fr.sharded_server_update(
+        fed_mesh_single, params, rs, seeds, 0.5, Distribution.RADEMACHER,
+        mode=mode, use_kernel=True, use_fused=True)
+    one = ops.server_update_fused(params, rs, seeds, 0.5,
+                                  Distribution.RADEMACHER, mode=mode,
+                                  use_pallas=False)
+    assert np.array_equal(np.asarray(mesh["w"]), np.asarray(one["w"]))
+
+
 def test_sharded_projection_single_psum(fed_mesh):
     """Sharded encode ≡ full-width projection within the k-scalar psum's
     fp32 reassociation — the round's only collective.  Single 1-D leaf

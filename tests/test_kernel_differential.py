@@ -15,6 +15,8 @@ Property-style contracts (DESIGN §3/§6/§7):
 Kernels run in TPU interpret mode on CPU; the shapes are deliberately
 tiny so the whole sweep stays in the fast test tier.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +25,7 @@ import pytest
 from repro.core.directions import FAMILIES
 from repro.core.projection import ProjectionMode, _proj_seed
 from repro.kernels import ops, ref
-from repro.kernels.reconstruct_apply import fused_reconstruct_apply
+from repro.kernels.reconstruct_apply import fused_plan, fused_reconstruct_apply
 from repro.kernels.seeded_projection import projection_blocks_kernel_call
 from repro.kernels.seeded_reconstruct import reconstruct_kernel_call
 
@@ -341,3 +343,115 @@ def test_fused_offset_col_slices_bit_identical():
              for i in range(4)]
     cat = np.concatenate([np.asarray(p) for p in parts], axis=1)
     assert np.array_equal(cat, np.asarray(full))
+
+
+# ---------------------------------------------------------------------------
+# Fused kernel orientation: rows along lanes (``fused_plan``)
+# ---------------------------------------------------------------------------
+#
+# Leaves whose rows tile the lanes and columns the sublanes close over
+# tiles of xᵀ.  Values depend on (row, col) alone and the chunk fold is
+# elementwise, so the orientation moves no bit: the Pallas kernel in
+# interpret mode must match the mirror and the oracle exactly, on
+# column counts that are not multiples of 128, masked blocks included.
+
+LANE_SHAPES = [(256, 320), (128, 960)]
+
+
+def _lane_tree(shape):
+    return {"x": jnp.asarray(np.random.RandomState(12).randn(*shape),
+                             jnp.float32)}
+
+
+@pytest.mark.parametrize("shape", LANE_SHAPES,
+                         ids=[f"{r}x{c}" for r, c in LANE_SHAPES])
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_fused_rows_along_lanes_bit_identical(family, k, shape):
+    dist = FAMILIES[family].distribution
+    plan = fused_plan(*shape)
+    assert plan.lanes_rows and plan.pad == 0, plan
+    tree = _lane_tree(shape)
+    n = 5
+    seeds = jnp.arange(n, dtype=jnp.uint32) + 21
+    rs = jnp.asarray(np.random.RandomState(13).randn(n, k), jnp.float32)
+    mode = ProjectionMode.BLOCK if k > 1 else ProjectionMode.FULL
+    got = {p: np.asarray(ops.server_update_fused(
+        tree, rs, seeds, 0.5, dist, mode=mode, use_pallas=p,
+        interpret=True)["x"]) for p in (True, False)}
+    want = np.asarray(ref.server_update_fused_ref(
+        tree, rs, seeds, 0.5, dist, num_projections=k, mode=mode)["x"])
+    assert np.array_equal(got[True], got[False]), (family, k, shape)
+    assert np.array_equal(got[True], want), (family, k, shape)
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("family", FAMILY_PARAMS)
+def test_fused_rows_along_lanes_offsets_bit_identical(family, k):
+    """Mesh-shard contract in the rows-along-lanes orientation: row
+    shards of 128 rows and column shards of 240 columns, each closed by
+    the Pallas kernel with its traced global offset, concatenate to the
+    full-width call bit for bit."""
+    dist = FAMILIES[family].distribution.value
+    n = 4
+    seeds = jnp.arange(n, dtype=jnp.uint32) + 5
+    rs = jnp.asarray(np.random.RandomState(14).randn(n, k), jnp.float32)
+    mode = ProjectionMode.BLOCK if k > 1 else ProjectionMode.FULL
+    for (rows, cols), axis, shards in (((256, 320), 0, 2),
+                                       ((128, 960), 1, 4)):
+        x = _lane_tree((rows, cols))["x"]
+        lo, hi = _leaf_bounds_full(rows, cols, k, mode)
+        close = functools.partial(
+            fused_reconstruct_apply, seeds=seeds, rs=rs, leaf_tag=3,
+            scale=0.25, distribution=dist, lo=lo, hi=hi, orig_cols=cols,
+            masked=k > 1, use_pallas=True, interpret=True)
+        full = np.asarray(close(x))
+        np.testing.assert_array_equal(
+            full, np.asarray(close(x, use_pallas=False)))
+        call = jax.jit(lambda blk, off: close(
+            blk, **{("row_offset", "col_offset")[axis]: off}))
+        per = x.shape[axis] // shards
+        parts = [np.asarray(call(jax.lax.slice_in_dim(x, i * per,
+                                                      (i + 1) * per,
+                                                      axis=axis),
+                                 jnp.uint32(i * per)))
+                 for i in range(shards)]
+        assert fused_plan(*parts[0].shape).lanes_rows
+        assert np.array_equal(np.concatenate(parts, axis=axis), full), \
+            (family, k, axis)
+
+
+def _smollm_shapes():
+    from repro.configs.registry import get_arch
+    return jax.eval_shape(get_arch("smollm-360m").init, jax.random.PRNGKey(0))
+
+
+def test_fused_plan_orientation_and_pad():
+    """smollm-360m's eight large leaves close rows-along-lanes with no
+    pad; its norms, ``(1, n)`` vectors and the paper MLP's leaves stay
+    as they lie, padded to their tiles."""
+    from repro.models.mlp_classifier import init_mlp
+
+    shapes = _smollm_shapes()
+    big = [s for s in jax.tree_util.tree_leaves(shapes) if s.ndim == 3
+           or s.shape == (49152, 960)]
+    assert len(big) == 8
+    for s in big:
+        rows, cols = ops.shape_2d(s.shape)
+        plan = fused_plan(rows, cols)
+        assert plan.lanes_rows and plan.pad == 0, (s.shape, plan)
+        tr, tc = plan.tile
+        assert rows % tr == 0 and tr % 128 == 0
+        assert cols % tc == 0 and tc % 8 == 0
+    for rows, cols in [(1, 960), (1, 4096), (32, 960)] + [
+            ops.shape_2d(p.shape)
+            for p in jax.tree_util.tree_leaves(init_mlp())]:
+        assert not fused_plan(rows, cols).lanes_rows, (rows, cols)
+    assert fused_plan(32, 960) == (False, (32, 256), 32 * 64)
+    assert fused_plan(1, 960) == (False, (8, 256), 8 * 1024 - 960)
+    # The whole tree on the Pallas path: 8 leaves along lanes, and the
+    # pad left is the norms' alone (two (32, 960), one (1, 960)).
+    assert ops.fused_tiling(shapes, use_pallas=True) == {
+        "lane_rows_leaves": 8, "pad_elements": 2 * 2048 + 7232}
+    assert ops.fused_tiling(shapes, use_pallas=False) == {
+        "lane_rows_leaves": 0, "pad_elements": 0}

@@ -20,11 +20,13 @@ ROUNDS = 3
 CHUNK = 8
 
 
-def _trace_job(log_dir, clients, xte, yte, cohort: int):
+def _trace_job(log_dir, clients, xte, yte, cohort: int,
+               projection_mode: str = "full"):
     """One traced job of ``cohort`` clients per round → its ``fed.*``
     events as (name, start, end, stats), by start."""
     cfg = RuntimeConfig(rounds=ROUNDS, population=64,
                         participation=cohort / 64, client_chunk=CHUNK,
+                        projection_mode=projection_mode,
                         downlink_mode="digest", eval_every=2,
                         scheduler=SchedulerConfig(mode="sync",
                                                   quorum_frac=0.8))
@@ -104,3 +106,21 @@ def test_spans_per_round_grow_only_by_one_wait_per_chunk(traced):
         del ca["fed.device_wait"], cb["fed.device_wait"]
         assert ca == cb
         assert max(cb.values()) == 1
+
+
+def test_fused_close_span_carries_its_tiling(tmp_path, digits8):
+    """A job closed by the fused kernel adds the tiling of its tree to
+    every ``fed.apply_round``: the leaves closed rows-along-lanes and
+    the elements computed and thrown away per client, both as
+    ``ops.fused_tiling`` gives them for this tree and backend."""
+    from repro.kernels.ops import fused_tiling
+
+    clients, xte, yte = digits8
+    events = _trace_job(tmp_path, clients, xte, yte, CHUNK,
+                        projection_mode="fused_kernel")
+    applies = [e[3] for e in events if e[0] == "fed.apply_round"]
+    assert len(applies) == ROUNDS
+    want = fused_tiling(init_mlp())
+    for stats in applies:
+        assert set(stats) == {"rows", "lane_rows_leaves", "pad_elements"}
+        assert {k: stats[k] for k in want} == want

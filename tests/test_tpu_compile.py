@@ -25,13 +25,17 @@ import pytest
 from repro.core.prng import Distribution
 from repro.core.projection import ProjectionMode
 from repro.kernels import ops
-from repro.kernels.reconstruct_apply import fused_reconstruct_apply
+from repro.kernels.reconstruct_apply import fused_plan, fused_reconstruct_apply
 from repro.kernels.tune import PALLAS_BLOCKS
 
 COHORT = 256
 EMBED = (49152, 960)     # smollm-360m token embedding
 MLP = (960, 2560)        # one smollm-360m w_up leaf
 TUNE = (512, 2048)       # the leaf the kernel benchmark tunes on
+# smollm-360m's stacked wk and w_down leaves as the close sees them: the
+# fused kernel tiles both rows-along-lanes, with 320 and 960 columns.
+WK = (30720, 320)
+W_DOWN = (81920, 960)
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +78,11 @@ CASES = [
     ("fused-rademacher-k1", (EMBED, MLP), 1, _fused(Distribution.RADEMACHER, 1)),
     ("fused-gaussian-k1", (EMBED, MLP), 1, _fused(Distribution.GAUSSIAN, 1)),
     ("fused-rademacher-k4-masked", (MLP,), 4, _fused(Distribution.RADEMACHER, 4)),
+    ("fused-lanes-rademacher-k1", (WK, W_DOWN), 1,
+     _fused(Distribution.RADEMACHER, 1)),
+    ("fused-lanes-gaussian-k1", (WK, W_DOWN), 1, _fused(Distribution.GAUSSIAN, 1)),
+    ("fused-lanes-rademacher-k4-masked", (WK,), 4,
+     _fused(Distribution.RADEMACHER, 4)),
     ("reconstruct-unmasked", (EMBED, MLP), 1, _reconstruct(1)),
     ("reconstruct-masked", (MLP,), 4, _reconstruct(4)),
     ("projection-k1", (EMBED, MLP), 1, None),
@@ -102,17 +111,14 @@ def test_kernel_compiles_for_v5e(one_chip, case, shapes, k, close):
     # One Mosaic kernel per leaf, and no host callback (the interpreter).
     assert text.count("tpu_custom_call") == len(shapes), case
     assert "callback" not in text, case
+    if case.startswith("fused-lanes"):
+        # Rows along lanes: no pad columns, so no pad in the program.
+        assert all(fused_plan(*s).lanes_rows for s in shapes), case
+        assert " pad(" not in text, case
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("block", PALLAS_BLOCKS,
-                         ids=[f"{br}x{bc}" for br, bc in PALLAS_BLOCKS])
-def test_every_tuner_tile_compiles_for_v5e(one_chip, block, dtype):
-    """Every tile the TPU autotuner may pick fits Mosaic's VMEM budget,
-    its (16, br, bc) float32 product scratch included; gaussian has the
-    largest kernel body of the families."""
-    x = jax.ShapeDtypeStruct(TUNE, dtype, sharding=one_chip)
+def _tile_compiles(one_chip, shape, block, dtype):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     rs = jax.ShapeDtypeStruct((COHORT, 1), jnp.float32, sharding=one_chip)
     seeds = jax.ShapeDtypeStruct((COHORT,), jnp.uint32, sharding=one_chip)
     close = functools.partial(fused_reconstruct_apply, leaf_tag=0, scale=1.0,
@@ -121,6 +127,32 @@ def test_every_tuner_tile_compiles_for_v5e(one_chip, block, dtype):
     text = jax.jit(close).lower(x, seeds, rs).compile().as_text()
     assert text.count("tpu_custom_call") == 1, block
     assert "callback" not in text, block
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("block", PALLAS_BLOCKS,
+                         ids=[f"{br}x{bc}" for br, bc in PALLAS_BLOCKS])
+def test_every_tuner_tile_compiles_for_v5e(one_chip, block, dtype):
+    """Every tile the TPU autotuner may pick fits Mosaic's VMEM budget,
+    its float32 product scratch of 16 tiles included; gaussian has the
+    largest kernel body of the families.  The tuned leaf closes
+    rows-along-lanes."""
+    _tile_compiles(one_chip, TUNE, block, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("block", PALLAS_BLOCKS,
+                         ids=[f"{br}x{bc}" for br, bc in PALLAS_BLOCKS])
+@pytest.mark.parametrize("shape", [W_DOWN, MLP], ids=["w_down", "mlp"])
+def test_every_tuner_tile_compiles_in_both_orientations(one_chip, shape,
+                                                        block, dtype):
+    """The same tiles on a 960-column leaf rows-along-lanes (240- or
+    480-column tiles) and on a leaf that closes as it lies (960 rows,
+    padded to the tile)."""
+    assert fused_plan(*shape).lanes_rows == (shape == W_DOWN)
+    _tile_compiles(one_chip, shape, block, dtype)
 
 
 def test_close_kernel_keeps_its_name_under_any_jit_or_scope(one_chip):

@@ -117,6 +117,17 @@ def test_candidates_prune_by_compile_budget():
     assert None in slabs(1 << 20)     # degenerate: fallback candidate
 
 
+def test_tpu_candidates_are_distinct_tilings():
+    """On the TPU a block is a budget that ``fused_plan`` fits to the
+    leaf: every budget is a candidate for a 960-column leaf closed
+    rows-along-lanes (240- and 480-column tiles, 128 or 256 rows), and
+    budgets that give the same tiling of a short leaf are timed once."""
+    blocks = lambda r, c: [tuple(x["block"])
+                           for x in tune._candidates("tpu", r, c)]
+    assert blocks(30720, 960) == list(tune.PALLAS_BLOCKS)
+    assert blocks(32, 960) == [(128, 256), (128, 512)]
+
+
 def test_cached_lookup_without_entry_is_none(tmp_path):
     assert tune.cached_fused_params(
         512, 256, 100, 3, "rademacher", backend="cpu",
